@@ -43,4 +43,6 @@ def run(csv):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run(lambda n, us, d: print(f"{n},{us:.1f},{d}"))

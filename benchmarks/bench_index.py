@@ -174,6 +174,8 @@ def _csv(name, us, derived):
 
 
 def main(argv=None) -> int:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--spec", required=True, metavar="FILE",
                     help="serialized IndexSpec benchmark JSON "

@@ -138,7 +138,8 @@ def sweep_point(kernel, m, d, k, *, candidates=None, iters=3, warmup=1,
              "us": None if c.time_s is None else c.time_s * 1e6,
              "ok": c.ok, "note": c.note}
             for c in res.candidates],
-        "roofline": predicted_vs_measured(
+        # interpreted timings have no roof to be measured against
+        "roofline": None if interpret else predicted_vs_measured(
             kernel, res.best_time_s, device_kind=device_kind,
             block_m=res.config.block_m or 256, m=tm, d=td, k=tk),
         "calib_mflops": calibrate(),
@@ -203,6 +204,8 @@ def check_defaults():
 
 
 def main(argv=None) -> int:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--sweep", action="store_true",
                     help="run the autotune sweep campaign")

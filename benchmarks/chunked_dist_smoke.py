@@ -53,6 +53,8 @@ def _timed_fit(fit, warm):
 
 
 def main(argv=None) -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--spec", default=str(SPECS / "chunked_dist_smoke.json"),
                     help="spec-file JSON (cluster_spec + workload)")

@@ -11,7 +11,7 @@ on the same spec.
 Writes ``benchmarks/artifacts/BENCH_dist_smoke.json`` so the shard_map path
 shows up in the perf trajectory and CI gate alongside the spec-file benches.
 
-  PYTHONPATH=src REPRO_PALLAS_INTERPRET=1 python -m benchmarks.dist_smoke
+  PYTHONPATH=src python -m benchmarks.dist_smoke
 """
 import json
 import pathlib
@@ -29,6 +29,8 @@ from repro.data.synthetic import blobs
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     spec = ClusterSpec(
         partition=PartitionSpec(scheme="equal", n_sub=8),
         local=LocalSpec(compression=5, iters=6),

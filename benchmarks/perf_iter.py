@@ -461,6 +461,8 @@ def run_stop_bench(n: int, d: int, k: int, *, tol: float = 1e-3,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     if "--stop" in sys.argv:
         ap.add_argument("--stop", action="store_true")

@@ -1,9 +1,9 @@
 #!/usr/bin/env bash
-# Real-device launcher for the kernel tile-autotune sweep campaign.
+# TPU launcher for the kernel tile-autotune sweep campaign.
 #
 # The CI sweep runs under the Pallas interpreter (correctness only); this
-# wrapper pins the allocator/XLA environment so the SAME sweep produces
-# meaningful numbers on a real GPU/TPU runner:
+# wrapper pins the TPU platform and the allocator/XLA environment so the
+# SAME sweep produces meaningful numbers on a TPU:
 #
 #   benchmarks/run_device.sh --sweep                       # full (M,d,K) grid
 #   benchmarks/run_device.sh --sweep --kernel assign
@@ -31,17 +31,10 @@ done
 # quiet the TF/XLA log spew so sweep output stays readable
 export TF_CPP_MIN_LOG_LEVEL=4
 
-# the kernels, not the interpreter: force compiled mode even if the
-# calling shell had CI settings exported.  REPRO_DEVICE_PLATFORM=tpu|gpu|cpu
-# pins the jax platform explicitly — without it jax autodetects, and a box
-# with libtpu installed but no TPU attached spends minutes retrying GCP
-# metadata before falling back
-export REPRO_PALLAS_INTERPRET=0
-if [[ -n "${REPRO_DEVICE_PLATFORM:-}" ]]; then
-  export JAX_PLATFORMS="$REPRO_DEVICE_PLATFORM"
-else
-  unset JAX_PLATFORMS 2>/dev/null || true
-fi
+# the kernels, not the interpreter: pin the TPU platform, so a host with
+# no TPU fails at start-up instead of quietly timing the CPU (kernels
+# interpret exactly when the backend is not a TPU)
+export JAX_PLATFORMS=tpu
 
 # keep f32 f32 — an accidental x64 default doubles every byte count the
 # roofline model predicts

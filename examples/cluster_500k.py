@@ -4,15 +4,15 @@ when multiple devices are available.
 
   PYTHONPATH=src python examples/cluster_500k.py [--n 500000] [--devices 8]
 
-With --devices N the script re-executes itself with N host devices and runs
-the real shard_map pipeline (one device = one batch of subclusters — the
-paper's CUDA-block mapping); the merge stage runs both replicated
-(paper-faithful) and distributed (beyond-paper, O(k*d) exchange per round).
+With more than one device the real shard_map pipeline runs too (one device
+= one batch of subclusters — the paper's CUDA-block mapping); the merge
+stage runs both replicated (paper-faithful) and distributed (beyond-paper,
+O(k*d) exchange per round).  On a TPU host that is every chip; on the CPU,
+--devices N splits the host into N devices (the flag only shapes the CPU
+backend, so it changes nothing on a chip).
 """
 import argparse
 import os
-import subprocess
-import sys
 import time
 
 
@@ -23,14 +23,11 @@ def main():
     ap.add_argument("--compression", type=int, default=5)
     args = ap.parse_args()
 
-    if args.devices and "XLA_FLAGS" not in os.environ:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
-                            f"{args.devices}")
-        sys.exit(subprocess.call(
-            [sys.executable, __file__, "--n", str(args.n),
-             "--compression", str(args.compression),
-             "--devices", str(args.devices)], env=env))
+    if args.devices:
+        # set before jax initialises; read by the CPU backend only
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + f" --xla_force_host_platform_device_count={args.devices}")
 
     import jax
     import jax.numpy as jnp

@@ -124,6 +124,18 @@ def plan(spec: ClusterSpec, data_shape: Optional[tuple] = None, *,
             from repro.kernels import autotune
             autotune.prewarm("lloyd", m=int(data_shape[0]),
                              d=int(data_shape[1]), k=spec.merge.k)
+    if data_shape is not None and len(data_shape) >= 2:
+        # a (K, d) the kernels cannot hold fails here, before any stage
+        # runs: the merge's K in every mode, and each level's local K
+        # where the pool accounting is exact (single mode)
+        m, d = int(data_shape[0]), int(data_shape[1])
+        ks = [spec.merge.k]
+        if spec.execution.mode in ("single", "auto") and mesh is None \
+                and source is None:
+            ks += [n // lv.n_sub for n, lv in
+                   zip(spec.pool_schedule(m), spec.level_schedule())]
+        for k in ks:
+            backend.check_fit(m, d, k)
     # telemetry resolves like the backend: the declarative string becomes a
     # live RunLogger exactly once, here
     run_logger = get_run_logger(logger if logger is not None
@@ -421,9 +433,9 @@ class SampledKMeans:
         """Nearest-center id per point (through the planned backend).
 
         Memory-bounded like ``transform``/``score``: the assignment runs
-        ``block`` rows at a time (O(block · k) working set, identical
-        labels to the dense evaluation; ``block=None`` forces the dense
-        path).  Accepts a resident array or a
+        ``block`` rows at a time (O(block · k) working set, the dense
+        evaluation's labels up to rounding ties; ``block=None`` forces the
+        dense path).  Accepts a resident array or a
         :class:`~repro.data.source.DataSource` (assigned chunk-by-chunk,
         so ``fit_predict`` works out-of-core — only the (n,) label vector
         materializes)."""
@@ -444,8 +456,8 @@ class SampledKMeans:
 
         Computed ``block`` rows at a time so the peak *intermediate*
         working set is O(block · k) however many points are scored (the
-        (m, k) return value is inherent); identical values to the dense
-        evaluation."""
+        (m, k) return value is inherent); the dense values up to
+        :data:`repro.core.metrics.BLOCKED_RTOL`."""
         self._check_fitted()
         return map_row_blocks(
             x, lambda b: pairwise_sqdist(b, self.centers_), block)

@@ -1,49 +1,28 @@
-"""jax version-compatibility shims.
+"""Mesh and shard_map entry points, in one place.
 
-The codebase targets the current jax API (``jax.shard_map``,
-``jax.make_mesh(axis_types=...)``, ``jax.set_mesh``); older jaxlibs (< 0.5)
-spell these ``jax.experimental.shard_map.shard_map(check_rep=...)``, plain
-``jax.make_mesh`` and the ``Mesh`` context manager.  Everything that builds
-meshes or shard_maps goes through this module so one import site absorbs
-the difference.
+Everything that builds meshes or shard_maps goes through this module, so
+the call sites share one spelling of ``jax.shard_map``,
+``jax.make_mesh(axis_types=...)`` and ``jax.set_mesh``.
 """
 from __future__ import annotations
 
 import jax
 
-_HAS_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-_HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-_HAS_SET_MESH = hasattr(jax, "set_mesh")
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` with the ``check_vma``/``check_rep`` rename papered
-    over (the replication check stays off either way — result types carry
-    NamedTuples the checker cannot infer)."""
-    if _HAS_NEW_SHARD_MAP:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    """``jax.shard_map`` with the replication check off by default (result
+    types carry NamedTuples the checker cannot infer)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_mesh(axis_shapes, axis_names) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with every axis Auto where axis types exist."""
-    if _HAS_AXIS_TYPES:
-        return jax.make_mesh(
-            axis_shapes, axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(axis_shapes, axis_names)
-    # pre-0.4.35: build the device grid by hand
-    from jax.experimental import mesh_utils
-    devices = mesh_utils.create_device_mesh(tuple(axis_shapes))
-    return jax.sharding.Mesh(devices, tuple(axis_names))
+    """``jax.make_mesh`` with every axis Auto."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def set_mesh(mesh: jax.sharding.Mesh):
     """Context manager installing ``mesh`` as the ambient mesh."""
-    if _HAS_SET_MESH:
-        return jax.set_mesh(mesh)
-    return mesh  # older jax: Mesh itself is the context manager
+    return jax.set_mesh(mesh)

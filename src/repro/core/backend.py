@@ -38,6 +38,8 @@ from typing import Callable, NamedTuple, Optional, Union
 import jax
 import jax.numpy as jnp
 
+from .metrics import HIGHEST, nearest
+
 Array = jax.Array
 
 ENV_VAR = "REPRO_KMEANS_BACKEND"
@@ -71,8 +73,9 @@ class LloydBackend:
         x = prep.xp[:prep.m, :prep.d]
         x2 = jnp.sum(x * x, axis=-1, keepdims=True)
         c2 = jnp.sum(centers * centers, axis=-1)
-        d2 = jnp.maximum(x2 + c2[None, :] - 2.0 * (x @ centers.T), 0.0)
-        idx = jnp.argmin(d2, axis=-1).astype(jnp.int32)
+        xc = jnp.matmul(x, centers.T, precision=HIGHEST)
+        d2 = jnp.maximum(x2 + c2[None, :] - 2.0 * xc, 0.0)
+        idx = nearest(d2).astype(jnp.int32)
         mind = jnp.take_along_axis(d2, idx[:, None], axis=-1)[:, 0]
         return idx, mind
 
@@ -83,7 +86,7 @@ class LloydBackend:
         k = centers.shape[0]
         onehot = jax.nn.one_hot(idx, k, dtype=jnp.float32) * w[:, None]
         x = prep.xp[:prep.m, :prep.d].astype(jnp.float32)
-        sums = onehot.T @ x
+        sums = jnp.matmul(onehot.T, x, precision=HIGHEST)
         counts = onehot.sum(axis=0)
         sse = jnp.sum(mind * w)
         return sums, counts, sse
@@ -92,6 +95,11 @@ class LloydBackend:
         _, mind = self.assign(prep, centers)
         return jnp.sum(mind * prep.wp[:prep.m].astype(jnp.float32))
 
+    def check_fit(self, m: int, d: int, k: int) -> None:
+        """Raise now if one f32 Lloyd pass over (m, d) points with k
+        centers cannot run on this backend (the planner calls this before
+        any stage runs).  The jnp reference has no tile limits."""
+
     # convenience for one-shot call sites (query paths, metrics)
     def assign_points(self, x: Array, centers: Array, *,
                       block: Optional[int] = None) -> tuple[Array, Array]:
@@ -99,7 +107,8 @@ class LloydBackend:
         the rows are processed that many at a time (``lax.map`` over fixed
         blocks, one ragged tail) so the peak working set is
         O(block · k) however many points are assigned — each row's result
-        depends on that row alone, so the values match the dense path."""
+        depends on that row alone, so the values match the dense path up
+        to :data:`repro.core.metrics.BLOCKED_RTOL`."""
         m = x.shape[0]
         if block is None or m <= block:
             return self.assign(self.prepare(x), centers)
@@ -162,6 +171,21 @@ class PallasBackend(LloydBackend):
     def _block_m(self, prep: Prepared) -> int:
         from repro.kernels.ops import padded_layout
         return padded_layout(prep.m, prep.d, self.block_m)[0]
+
+    def _tiles(self, m: int, d: int, k: int) -> tuple[int, int]:
+        """(block_m, block_k) one f32 pass at this shape runs with."""
+        from repro.kernels.ops import padded_layout
+        from repro.kernels.tiles import clamp_block_k
+        return (padded_layout(m, d, self.block_m)[0],
+                clamp_block_k(k, self.block_k))
+
+    def check_fit(self, m: int, d: int, k: int) -> None:
+        """Raise :class:`repro.kernels.tiles.TileError` when the (k, d)
+        accumulators of the fused pass cannot stay in the VMEM budget."""
+        from repro.kernels.tiles import pad_to, require_lloyd_vmem
+        bm, bk = self._tiles(m, d, k)
+        require_lloyd_vmem(bm, bk, pad_to(k, bk), d, 4,
+                           kernel=f"{self.name} backend")
 
     def _pad_centers(self, prep: Prepared, centers: Array) -> Array:
         dp = prep.xp.shape[1]
@@ -277,6 +301,13 @@ class PallasTunedBackend(PallasFusedBackend):
 
     def _block_k(self, prep: Prepared, k: int) -> int:
         return self._config(prep.m, prep.d, k, prep.xp.dtype).block_k
+
+    def _tiles(self, m: int, d: int, k: int) -> tuple[int, int]:
+        from repro.kernels.ops import padded_layout
+        from repro.kernels.tiles import clamp_block_k
+        bm = self._config(m, d, self._hint(), jnp.float32).block_m
+        return (padded_layout(m, d, bm)[0],
+                clamp_block_k(k, self._config(m, d, k, jnp.float32).block_k))
 
     def assign(self, prep: Prepared, centers: Array) -> tuple[Array, Array]:
         from repro.kernels import pad_to

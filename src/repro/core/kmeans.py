@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from .backend import BackendSpec, LloydBackend, AssignFnBackend, get_backend
+from .metrics import HIGHEST, nearest
 from .spec import StopSpec
 
 Array = jax.Array
@@ -46,14 +47,14 @@ def pairwise_sqdist(x: Array, c: Array) -> Array:
     """
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)
     c2 = jnp.sum(c * c, axis=-1)
-    xc = x @ c.T
+    xc = jnp.matmul(x, c.T, precision=HIGHEST)
     return jnp.maximum(x2 + c2[None, :] - 2.0 * xc, 0.0)
 
 
 def assign_jnp(x: Array, c: Array) -> tuple[Array, Array]:
     """Reference assignment step: nearest center id + its squared distance."""
     d = pairwise_sqdist(x, c)
-    idx = jnp.argmin(d, axis=-1).astype(jnp.int32)
+    idx = nearest(d).astype(jnp.int32)
     mind = jnp.take_along_axis(d, idx[:, None].astype(jnp.int32), axis=-1)[:, 0]
     return idx, mind
 
@@ -67,7 +68,7 @@ def update_centers(
     """Weighted centroid update via one-hot matmul (TPU-friendly scatter)."""
     onehot = jax.nn.one_hot(idx, k, dtype=x.dtype) * weights[:, None]
     counts = onehot.sum(axis=0)
-    sums = onehot.T @ x
+    sums = jnp.matmul(onehot.T, x, precision=HIGHEST)
     new = sums / jnp.maximum(counts, 1e-12)[:, None]
     keep_old = (counts <= 0.0)[:, None]
     return jnp.where(keep_old, old_centers, new), counts
@@ -197,8 +198,7 @@ def kmeans_parallel_init(x: Array, weights: Array, k: int, key: Array,
     # the sequential k-means++ on the (small) candidate set only
     d2 = pairwise_sqdist(x, cand)
     d2 = jnp.where(cand_valid[None, :], d2, jnp.inf)
-    nearest = jnp.argmin(d2, axis=-1)
-    cand_w = (jax.nn.one_hot(nearest, n_cand, dtype=jnp.float32)
+    cand_w = (jax.nn.one_hot(nearest(d2), n_cand, dtype=jnp.float32)
               * weights[:, None].astype(jnp.float32)).sum(axis=0)
     cand_w = jnp.where(cand_valid, jnp.maximum(cand_w, 1e-12), 0.0)
     return kmeans_pp_init(cand, cand_w.astype(x.dtype), k, key_reduce)

@@ -25,6 +25,8 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from .metrics import HIGHEST, nearest
+
 Array = jax.Array
 
 
@@ -104,9 +106,9 @@ def unequal_partition(
     d = (
         jnp.sum(x * x, -1, keepdims=True)
         + jnp.sum(lms * lms, -1)[None, :]
-        - 2.0 * (x @ lms.T)
+        - 2.0 * jnp.matmul(x, lms.T, precision=HIGHEST)
     )
-    assign = jnp.argmin(d, axis=-1).astype(jnp.int32)
+    assign = nearest(d).astype(jnp.int32)
 
     order = jnp.argsort(assign, stable=True).astype(jnp.int32)
     sorted_assign = assign[order]

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import ClusterSpec, sampled_kmeans
+from repro.core.metrics import HIGHEST, nearest
 
 
 def doc_sketch(tokens: np.ndarray, dim: int = 32) -> np.ndarray:
@@ -47,8 +48,9 @@ class ClusterBalancedSampler:
                              key=jax.random.PRNGKey(seed))
         d2 = (jnp.sum(sketches ** 2, -1, keepdims=True)
               + jnp.sum(res.centers ** 2, -1)[None, :]
-              - 2.0 * sketches @ res.centers.T)
-        self.assignment = np.asarray(jnp.argmin(d2, -1))
+              - 2.0 * jnp.matmul(sketches, res.centers.T,
+                                 precision=HIGHEST))
+        self.assignment = np.asarray(nearest(d2))
         self.n_clusters = spec.merge.k
         self.by_cluster = [np.nonzero(self.assignment == c)[0]
                            for c in range(self.n_clusters)]

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 from repro.core.backend import BackendSpec
 from repro.core.kmeans import kmeans
-from repro.core.metrics import map_row_blocks
+from repro.core.metrics import HIGHEST, map_row_blocks, nearest
 
 from .spec import PQSpec
 
@@ -70,9 +70,9 @@ def encode_residuals(residuals: Array, codebooks: Array, *,
 
     def dense(rows: Array) -> Array:
         r = rows.astype(jnp.float32).reshape(rows.shape[0], m, ds)
-        dots = jnp.einsum("nms,mcs->nmc", r, cb)
+        dots = jnp.einsum("nms,mcs->nmc", r, cb, precision=HIGHEST)
         d2 = jnp.sum(r * r, -1)[..., None] + cb2[None] - 2.0 * dots
-        return jnp.argmin(d2, axis=-1).astype(jnp.uint8)
+        return nearest(d2).astype(jnp.uint8)
 
     return map_row_blocks(residuals, dense, block)
 
@@ -99,7 +99,7 @@ def build_luts(queries: Array, probe_cells: Array, coarse_centers: Array,
     qr = (queries.astype(jnp.float32)[:, None, :]
           - coarse_centers[probe_cells])                  # (Q, P, d)
     qs = qr.reshape(qr.shape[0], qr.shape[1], m, ds)      # (Q, P, m, ds)
-    dots = jnp.einsum("qpms,mcs->qpmc", qs, cb)
+    dots = jnp.einsum("qpms,mcs->qpmc", qs, cb, precision=HIGHEST)
     cb2 = jnp.sum(cb * cb, axis=-1)                       # (m, C)
     return (jnp.sum(qs * qs, -1)[..., None]
             + cb2[None, None] - 2.0 * dots)

@@ -13,18 +13,14 @@
   tune_table.py — committed per-device-kind tile defaults
 
 Kernels target TPU (pl.pallas_call + BlockSpec VMEM tiling) and are validated
-on CPU with ``interpret=True``; ``default_interpret()`` flips automatically.
+on CPU with ``interpret=True``; ``default_interpret()`` is true exactly when
+the default backend is not a TPU, so on a TPU every kernel is compiled.
 """
 from __future__ import annotations
 
-import os
-
 
 def default_interpret() -> bool:
-    """interpret=True everywhere except a real TPU backend."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
+    """interpret=True everywhere except a TPU backend — no override."""
     import jax
     return jax.default_backend() != "tpu"
 

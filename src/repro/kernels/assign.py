@@ -20,28 +20,43 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .tiles import clamp_block_k, require_block_m
+from .tiles import clamp_block_k, compiler_params, require_block_m
 
-NEG = -1
-_BIG = 3.0e38  # ~f32 max; used to mask padded center columns
+_BIG = 3.0e38  # ~f32 max; used to mask padded center rows
+
+
+def distance_tile(x: jax.Array, c: jax.Array, ki, *, block_k: int,
+                  k_actual: int) -> tuple[jax.Array, jax.Array]:
+    """Nearest center of one K tile for one M tile, shared by the assign
+    and fused Lloyd kernels: (bm, d) points, (bk, d) centers ->
+    ((1, bm) f32 min distance, (1, bm) int32 global argmin).
+
+    The distance tile is laid out (bk, bm) — centers on sublanes, points
+    on lanes — so the min/argmin reduce over sublanes and land as
+    lane-dense rows, the layout the (1, M) outputs are stored in.  Rows
+    past ``k_actual`` (K padding) are masked out of the argmin."""
+    x = x.astype(jnp.float32)
+    c = c.astype(jnp.float32)
+    x2 = jnp.sum(x * x, axis=-1, keepdims=True).T          # (1, bm)
+    c2 = jnp.sum(c * c, axis=-1, keepdims=True)            # (bk, 1)
+    # full-f32 MXU passes: the expansion cancels |x|² against 2x·c, which
+    # a single bf16 pass (the TPU default) would swamp
+    xc = jax.lax.dot_general(
+        c, x, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    d2 = jnp.maximum(x2 + c2 - 2.0 * xc, 0.0)              # (bk, bm)
+    row = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, d2.shape, 0)
+    d2 = jnp.where(row < k_actual, d2, _BIG)
+    local_min = jnp.min(d2, axis=0, keepdims=True)
+    local_arg = ki * block_k + jnp.argmin(
+        d2, axis=0, keepdims=True).astype(jnp.int32)
+    return local_min, local_arg
 
 
 def _assign_kernel(x_ref, c_ref, idx_ref, dist_ref, *, block_k: int, k_actual: int):
     ki = pl.program_id(1)
-    x = x_ref[...].astype(jnp.float32)          # (bm, d)
-    c = c_ref[...].astype(jnp.float32)          # (bk, d)
-    x2 = jnp.sum(x * x, axis=-1, keepdims=True)           # (bm, 1)
-    c2 = jnp.sum(c * c, axis=-1)[None, :]                 # (1, bk)
-    xc = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    d2 = jnp.maximum(x2 + c2 - 2.0 * xc, 0.0)             # (bm, bk)
-
-    col = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1)
-    d2 = jnp.where(col < k_actual, d2, _BIG)
-
-    local_min = jnp.min(d2, axis=-1)                      # (bm,)
-    local_arg = (ki * block_k
-                 + jnp.argmin(d2, axis=-1).astype(jnp.int32))  # (bm,)
+    local_min, local_arg = distance_tile(x_ref[...], c_ref[...], ki,
+                                         block_k=block_k, k_actual=k_actual)
 
     @pl.when(ki == 0)
     def _init():
@@ -92,13 +107,14 @@ def assign_argmin_pallas(
             pl.BlockSpec((block_k, d), lambda i, j: (j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_m,), lambda i, j: (i,)),
-            pl.BlockSpec((block_m,), lambda i, j: (i,)),
+            pl.BlockSpec((1, block_m), lambda i, j: (0, i)),
+            pl.BlockSpec((1, block_m), lambda i, j: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((m,), jnp.int32),
-            jax.ShapeDtypeStruct((m,), jnp.float32),
+            jax.ShapeDtypeStruct((1, m), jnp.int32),
+            jax.ShapeDtypeStruct((1, m), jnp.float32),
         ],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(x, c)
-    return idx, dist
+    return idx[0], dist[0]

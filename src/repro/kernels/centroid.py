@@ -13,7 +13,17 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .tiles import require_block_m
+from .tiles import compiler_params, require_block_m, require_lloyd_vmem
+
+
+def weighted_onehot_t(idx: jax.Array, w: jax.Array, kp: int) -> jax.Array:
+    """Transposed weighted one-hot of one M tile, shared by the centroid
+    and fused Lloyd kernels: (1, bm) int32 ids, (1, bm) f32 weights ->
+    (kp, bm) with ``w`` where row == id.  ``onehot @ x`` is then the
+    tile's per-cluster weighted sum as a plain MXU matmul, and a lane
+    reduction gives the counts."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (kp, idx.shape[1]), 0)
+    return jnp.where(rows == idx, w, 0.0)
 
 
 def _centroid_kernel(x_ref, idx_ref, w_ref, sums_ref, counts_ref, *, k: int):
@@ -24,15 +34,14 @@ def _centroid_kernel(x_ref, idx_ref, w_ref, sums_ref, counts_ref, *, k: int):
         sums_ref[...] = jnp.zeros_like(sums_ref)
         counts_ref[...] = jnp.zeros_like(counts_ref)
 
-    x = x_ref[...].astype(jnp.float32)            # (bm, d)
-    idx = idx_ref[...]                            # (bm, 1) int32
-    w = w_ref[...].astype(jnp.float32)            # (bm, 1)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], k), 1)
-    onehot = jnp.where(cols == idx, 1.0, 0.0) * w            # (bm, k)
+    x = x_ref[...].astype(jnp.float32)                       # (bm, d)
+    onehot = weighted_onehot_t(idx_ref[...],
+                               w_ref[...].astype(jnp.float32), k)  # (k, bm)
     sums_ref[...] += jax.lax.dot_general(
-        onehot, x, (((0,), (0,)), ((), ())),
+        onehot, x, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)                  # (k, d)
-    counts_ref[...] += jnp.sum(onehot, axis=0, keepdims=True).T  # (k, 1)
+    counts_ref[...] += jnp.sum(onehot, axis=1, keepdims=True)  # (k, 1)
 
 
 def centroid_update_pallas(
@@ -55,6 +64,8 @@ def centroid_update_pallas(
         interpret = default_interpret()
     m, d = x.shape
     require_block_m(m, block_m, kernel="centroid_update_pallas")
+    require_lloyd_vmem(block_m, 0, k, d, x.dtype.itemsize,
+                       kernel="centroid_update_pallas")
     grid = (m // block_m,)
 
     sums, counts = pl.pallas_call(
@@ -62,8 +73,8 @@ def centroid_update_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_m, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_m, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, block_m), lambda i: (0, i)),
+            pl.BlockSpec((1, block_m), lambda i: (0, i)),
         ],
         out_specs=[
             pl.BlockSpec((k, d), lambda i: (0, 0)),
@@ -73,6 +84,7 @@ def centroid_update_pallas(
             jax.ShapeDtypeStruct((k, d), jnp.float32),
             jax.ShapeDtypeStruct((k, 1), jnp.float32),
         ],
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(x, idx.reshape(m, 1), w.reshape(m, 1))
+    )(x, idx.reshape(1, m), w.reshape(1, m))
     return sums, counts[:, 0]

@@ -10,11 +10,14 @@ single-pass sufficient-statistics aggregation of Scalable K-Means++
 
   * grid = (M tiles, K tiles), K minor.  Per M-tile the kernel walks the
     K tiles sequentially carrying a running (min distance, argmin) pair in
-    the per-tile output VMEM blocks (assign.py's idiom, unchanged);
+    the per-tile output VMEM blocks (assign.py's ``distance_tile``); ids,
+    distances and weights travel as lane-dense ``(1, M)`` rows;
   * on the *last* K tile the winner is final, so the kernel immediately
     folds the tile into the (K, d) ``sums`` / (K, 1) ``counts`` VMEM
-    accumulators via a weighted one-hot matmul on the MXU — the assignment
-    and the one-hot matrix never round-trip through HBM;
+    accumulators via a weighted (K, bm) one-hot matmul on the MXU — the
+    assignment and the one-hot matrix never round-trip through HBM; the
+    resident accumulators are what :func:`repro.kernels.tiles.
+    require_lloyd_vmem` budgets;
   * the weighted SSE contribution ``sum(best_dist * w)`` is accumulated in
     the same place, so one pass yields everything a Lloyd iteration needs;
   * distances and accumulation are fp32 regardless of the input dtype
@@ -34,9 +37,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .tiles import clamp_block_k, require_block_m
-
-_BIG = 3.0e38  # ~f32 max; masks padded center columns out of the argmin
+from .assign import distance_tile
+from .centroid import weighted_onehot_t
+from .tiles import (clamp_block_k, compiler_params, require_block_m,
+                    require_lloyd_vmem)
 
 
 def _lloyd_kernel(x_ref, w_ref, c_ref, idx_ref, dist_ref, sums_ref,
@@ -51,20 +55,8 @@ def _lloyd_kernel(x_ref, w_ref, c_ref, idx_ref, dist_ref, sums_ref,
         counts_ref[...] = jnp.zeros_like(counts_ref)
         sse_ref[...] = jnp.zeros_like(sse_ref)
 
-    x = x_ref[...].astype(jnp.float32)                    # (bm, d)
-    c = c_ref[...].astype(jnp.float32)                    # (bk, d)
-    x2 = jnp.sum(x * x, axis=-1, keepdims=True)           # (bm, 1)
-    c2 = jnp.sum(c * c, axis=-1)[None, :]                 # (1, bk)
-    xc = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    d2 = jnp.maximum(x2 + c2 - 2.0 * xc, 0.0)             # (bm, bk)
-
-    col = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1)
-    d2 = jnp.where(col < k_actual, d2, _BIG)
-
-    local_min = jnp.min(d2, axis=-1)                      # (bm,)
-    local_arg = (ki * block_k
-                 + jnp.argmin(d2, axis=-1).astype(jnp.int32))
+    local_min, local_arg = distance_tile(x_ref[...], c_ref[...], ki,
+                                         block_k=block_k, k_actual=k_actual)
 
     @pl.when(ki == 0)
     def _init_best():
@@ -82,17 +74,16 @@ def _lloyd_kernel(x_ref, w_ref, c_ref, idx_ref, dist_ref, sums_ref,
     def _accumulate():
         # the running best is final for this M tile: fold it into the
         # (K, d) VMEM accumulators right here — no HBM round-trip
-        w = w_ref[...].astype(jnp.float32)                # (bm, 1)
-        idx = idx_ref[...]                                # (bm,)
-        best = dist_ref[...]                              # (bm,)
-        kp = sums_ref.shape[0]
-        cols = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], kp), 1)
-        onehot = jnp.where(cols == idx[:, None], 1.0, 0.0) * w  # (bm, kp)
+        x = x_ref[...].astype(jnp.float32)                # (bm, d)
+        w = w_ref[...].astype(jnp.float32)                # (1, bm)
+        best = dist_ref[...]                              # (1, bm)
+        onehot = weighted_onehot_t(idx_ref[...], w, sums_ref.shape[0])
         sums_ref[...] += jax.lax.dot_general(
-            onehot, x, (((0,), (0,)), ((), ())),
+            onehot, x, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)           # (kp, d)
-        counts_ref[...] += jnp.sum(onehot, axis=0, keepdims=True).T
-        sse_ref[...] = sse_ref[...] + jnp.sum(best * w[:, 0])
+        counts_ref[...] += jnp.sum(onehot, axis=1, keepdims=True)
+        sse_ref[...] = sse_ref[...] + jnp.sum(best * w)
 
 
 def lloyd_step_pallas(
@@ -126,6 +117,8 @@ def lloyd_step_pallas(
     require_block_m(m, block_m, kernel="lloyd_step_pallas")
     block_k = clamp_block_k(k, block_k)
     kp = -(-k // block_k) * block_k
+    require_lloyd_vmem(block_m, block_k, kp, d, x.dtype.itemsize,
+                       kernel="lloyd_step_pallas")
     if kp != k:
         c = jnp.pad(c, ((0, kp - k), (0, 0)))
     nk = kp // block_k
@@ -136,23 +129,24 @@ def lloyd_step_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_m, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, block_m), lambda i, j: (0, i)),
             pl.BlockSpec((block_k, d), lambda i, j: (j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_m,), lambda i, j: (i,)),
-            pl.BlockSpec((block_m,), lambda i, j: (i,)),
+            pl.BlockSpec((1, block_m), lambda i, j: (0, i)),
+            pl.BlockSpec((1, block_m), lambda i, j: (0, i)),
             pl.BlockSpec((kp, d), lambda i, j: (0, 0)),
             pl.BlockSpec((kp, 1), lambda i, j: (0, 0)),
             pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((m,), jnp.int32),
-            jax.ShapeDtypeStruct((m,), jnp.float32),
+            jax.ShapeDtypeStruct((1, m), jnp.int32),
+            jax.ShapeDtypeStruct((1, m), jnp.float32),
             jax.ShapeDtypeStruct((kp, d), jnp.float32),
             jax.ShapeDtypeStruct((kp, 1), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(x, w.reshape(m, 1), c)
-    return sums[:k], counts[:k, 0], sse[0, 0], idx, dist
+    )(x, w.reshape(1, m), c)
+    return sums[:k], counts[:k, 0], sse[0, 0], idx[0], dist[0]

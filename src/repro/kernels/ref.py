@@ -5,15 +5,21 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# full-f32 MXU passes: a TPU's default f32 matmul is one bf16 pass
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def assign_argmin_ref(x: jax.Array, c: jax.Array) -> tuple[jax.Array, jax.Array]:
     x = x.astype(jnp.float32)
     c = c.astype(jnp.float32)
     d2 = (jnp.sum(x * x, -1, keepdims=True)
           + jnp.sum(c * c, -1)[None, :]
-          - 2.0 * (x @ c.T))
+          - 2.0 * jnp.matmul(x, c.T, precision=HIGHEST))
     d2 = jnp.maximum(d2, 0.0)
-    idx = jnp.argmin(d2, axis=-1).astype(jnp.int32)
+    # min as a value, then its first index: a bare argmin is narrowed to
+    # bf16 by XLA:TPU (see repro.core.metrics.nearest)
+    idx = jnp.argmax(d2 == jnp.min(d2, -1, keepdims=True),
+                     axis=-1).astype(jnp.int32)
     mind = jnp.take_along_axis(d2, idx[:, None], axis=-1)[:, 0]
     return idx, mind
 
@@ -23,7 +29,7 @@ def centroid_update_ref(
 ) -> tuple[jax.Array, jax.Array]:
     x = x.astype(jnp.float32)
     onehot = jax.nn.one_hot(idx, k, dtype=jnp.float32) * w.astype(jnp.float32)[:, None]
-    return onehot.T @ x, onehot.sum(axis=0)
+    return jnp.matmul(onehot.T, x, precision=HIGHEST), onehot.sum(axis=0)
 
 
 def lloyd_step_ref(
@@ -47,7 +53,7 @@ def adc_scan_ref(luts: jax.Array, codes: jax.Array) -> jax.Array:
     b, m, c = luts.shape
     onehot = jax.nn.one_hot(codes.astype(jnp.int32), c,
                             dtype=jnp.float32)            # (B, L, m, C)
-    return jnp.einsum("blmc,bmc->bl", onehot, luts)
+    return jnp.einsum("blmc,bmc->bl", onehot, luts, precision=HIGHEST)
 
 
 def cluster_attn_decode_ref(

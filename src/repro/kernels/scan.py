@@ -25,7 +25,7 @@ accumulation:
 
 ``adc_scan`` is the public entry: a ``jnp`` reference backend
 (``take_along_axis`` gather) and the Pallas kernel with interpret-mode
-parity on CPU (``REPRO_PALLAS_INTERPRET=1``), selected like the
+parity on CPU (interpreted off a TPU), selected like the
 ``LloydBackend`` registry (``"auto"`` = Pallas on TPU, jnp elsewhere,
 overridable via ``REPRO_SCAN_BACKEND``).
 """
@@ -56,15 +56,17 @@ def _adc_kernel(codes_ref, lut_ref, out_ref, *, m: int, c: int):
     code = codes_ref[0]                                   # (bl, m) int32
     lut = lut_ref[0].astype(jnp.float32)                  # (m, C)
     bl = code.shape[0]
-    acc = jnp.zeros((bl,), jnp.float32)
+    acc = jnp.zeros((1, bl), jnp.float32)
     cols = jax.lax.broadcasted_iota(jnp.int32, (bl, c), 1)
     for j in range(m):        # m is static and small: unrolled lookups
-        onehot = jnp.where(cols == code[:, j][:, None], 1.0, 0.0)
-        # (bl, C) @ (C, 1): the gather as an MXU matvec against one LUT row
+        onehot = jnp.where(cols == code[:, j:j + 1], 1.0, 0.0)
+        # (1, C) x (bl, C)^T: the gather as an MXU matvec against one LUT
+        # row, landing lane-dense like the (1, bl) output block
         acc = acc + jax.lax.dot_general(
-            onehot, lut[j][:, None], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)[:, 0]
-    out_ref[0, :] = acc
+            lut[j:j + 1], onehot, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+    out_ref[0] = acc
 
 
 def adc_scan_pallas(luts: jax.Array, codes: jax.Array, *,
@@ -109,11 +111,11 @@ def adc_scan_pallas(luts: jax.Array, codes: jax.Array, *,
             pl.BlockSpec((1, block_l, m), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, m, c), lambda i, j: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_l), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((b, lp), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, block_l), lambda i, j: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((b, 1, lp), jnp.float32),
         interpret=interpret,
     )(codes, luts)
-    return out[:, :l]
+    return out[:, 0, :l]
 
 
 def resolve_scan_backend(name: str | None = None) -> str:

@@ -9,43 +9,34 @@ and validate it (:func:`validate_table` is the ``--check-defaults`` hook in
 Matching is by *device-kind substring*: the first pattern (insertion
 order) whose lowercase form appears in the lowercase
 ``jax.devices()[0].device_kind`` wins; ``"*"`` matches everything and
-belongs last.  Entries come from real-device sweep campaigns
+belongs last.  Entries are meant to come from real-device sweep campaigns
 (``benchmarks/bench_kernels.py --sweep`` under ``benchmarks/
 run_device.sh``); refresh them by re-running the sweep on the device kind
-and copying the winners here.  A device kind with no row simply falls
-through to the hardcoded per-kernel default, so an unknown accelerator is
-never an error.
+and copying the winners here.
 """
 from __future__ import annotations
 
 from typing import Optional
 
 # {kernel: {device-kind substring pattern: TileConfig fields}}
-# v5e rows: 16 MB VMEM favours the wider M tile for the fused kernel (one
-# extra grid step of x amortised over more MXU work); v4's smaller VMEM
-# keeps the historical 256x256.  CPU rows pin the interpret-mode smoke
-# values so the tiny CI sweep and the table agree.
+# The "TPU v5 lite" (v5e) rows compile for a described v5e at the paper
+# and embedding shapes (tests/test_tpu_compile.py); no sweep has timed
+# them.  The "*" rows pin the interpret-mode CI smoke values.
 TABLE: dict = {
     "lloyd": {
         "TPU v5 lite": {"block_m": 512, "block_k": 256},
-        "TPU v5": {"block_m": 512, "block_k": 256},
-        "TPU v4": {"block_m": 256, "block_k": 256},
         "*": {"block_m": 256, "block_k": 256},
     },
     "assign": {
         "TPU v5 lite": {"block_m": 512, "block_k": 256},
-        "TPU v5": {"block_m": 512, "block_k": 256},
-        "TPU v4": {"block_m": 256, "block_k": 256},
         "*": {"block_m": 256, "block_k": 256},
     },
     "centroid": {
         "TPU v5 lite": {"block_m": 1024},
-        "TPU v5": {"block_m": 1024},
         "*": {"block_m": 512},
     },
     "scan": {
         "TPU v5 lite": {"block_l": 512},
-        "TPU v5": {"block_l": 512},
         "*": {"block_l": 256},
     },
 }

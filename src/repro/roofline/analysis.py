@@ -24,7 +24,6 @@ from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Optional
 
 # --- TPU v5e hardware constants (per chip) ---------------------------------
 PEAK_BF16 = 197e12        # FLOP/s
@@ -161,24 +160,23 @@ def model_flops(cfg, shape, kind: str) -> float:
 # measured per accepted tile config).
 # ---------------------------------------------------------------------------
 
-# per-chip roofs by device-kind substring (first match wins, "*" last);
-# values are peak dense FLOP/s and HBM bandwidth
+# per-chip roofs by device-kind substring: peak dense bf16 FLOP/s and HBM
+# bandwidth.  TPU v5e: Google Cloud documentation, "TPU v5e" (197 TFLOP/s
+# bf16, 819 GB/s HBM).  A device kind with no row is an error, not a default.
 DEVICE_ROOFS = {
     "TPU v5 lite": {"peak_flops": PEAK_BF16, "hbm_bw": HBM_BW},
-    "TPU v4": {"peak_flops": 275e12, "hbm_bw": 1228e9},
-    "*": {"peak_flops": PEAK_BF16, "hbm_bw": HBM_BW},
 }
 
 
-def device_roof(device_kind: Optional[str] = None) -> dict:
-    """Roof constants for a device kind (substring match, ``"*"``
-    fallback)."""
-    if device_kind:
-        needle = device_kind.lower()
-        for pat, roof in DEVICE_ROOFS.items():
-            if pat != "*" and pat.lower() in needle:
-                return roof
-    return DEVICE_ROOFS["*"]
+def device_roof(device_kind: str) -> dict:
+    """Roof constants for a device kind (substring match); raises
+    ``KeyError`` for a device the table does not know."""
+    needle = (device_kind or "").lower()
+    for pat, roof in DEVICE_ROOFS.items():
+        if pat.lower() in needle:
+            return roof
+    raise KeyError(f"device_roof: no published roof for device kind "
+                   f"{device_kind!r}; known: {sorted(DEVICE_ROOFS)}")
 
 
 def _ceil_div(a: int, b: int) -> int:
@@ -226,14 +224,13 @@ def kernel_cost(kernel: str, *, dtype_bytes: int = 4, block_m: int = 256,
 
 
 def predicted_vs_measured(kernel: str, measured_s: float, *,
-                          device_kind: Optional[str] = None,
+                          device_kind: str,
                           dtype_bytes: int = 4, block_m: int = 256,
                           block_l: int = 256, **dims) -> dict:
     """One accepted sweep config -> its roofline report: predicted time
     (max of the compute and memory terms on this device's roofs), the
-    dominant term, and measured/predicted efficiency.  Interpret-mode
-    numbers make ``efficiency`` meaningless but the predicted side still
-    documents what the config *should* cost on hardware."""
+    dominant term, and measured/predicted efficiency.  Only meaningful
+    for a compiled run on a device :func:`device_roof` knows."""
     cost = kernel_cost(kernel, dtype_bytes=dtype_bytes, block_m=block_m,
                        block_l=block_l, **dims)
     roof = device_roof(device_kind)

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 
 from repro.core.backend import BackendSpec, LloydBackend, get_backend
 from repro.core.kmeans import kmeans, pairwise_sqdist
-from repro.core.metrics import sse as sse_fn
+from repro.core.metrics import nearest, sse as sse_fn
 from repro.core.pipeline import chunk_fold, reduce_pool
 from repro.core.spec import ClusterSpec, LevelSpec, StopSpec
 from repro.core.subcluster import feature_scale, unscale
@@ -147,7 +147,7 @@ def reseed_dead_centers(centers: Array, coreset: Array, coreset_w: Array,
     from an all-zero init state) land on k distinct regions."""
     k = centers.shape[0]
     d2 = pairwise_sqdist(coreset, centers)  # one matrix serves both uses
-    idx = jnp.argmin(d2, axis=1)
+    idx = nearest(d2, axis=1)
     support = (jax.nn.one_hot(idx, k, dtype=coreset.dtype)
                * coreset_w[:, None]).sum(axis=0)
     dead = support <= threshold

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.core.backend import BackendSpec, get_backend
 from repro.core.kmeans import kmeans
+from repro.core.metrics import nearest
 from repro.core.spec import ClusterSpec, StopSpec
 
 
@@ -43,7 +44,7 @@ def quantize_leaf(g: jax.Array, levels: int, key,
     res = kmeans(samp, levels, stop=stop, key=key, init=init,
                  backend=backend)
     code = res.centers[:, 0]                       # (levels,)
-    idx = jnp.argmin(jnp.abs(flat - code[None, :]), axis=-1)
+    idx = nearest(jnp.abs(flat - code[None, :]))
     deq = code[idx].reshape(g.shape)
     return deq.astype(g.dtype), {"codebook": code}
 

@@ -179,6 +179,19 @@ def test_committed_table_loads_and_validates():
     assert any(tune_table.load_default("lloyd", "Quantum FPGA 9000"))
 
 
+def test_device_roof_knows_v5e_and_nothing_else():
+    from repro.roofline.analysis import device_roof, predicted_vs_measured
+    roof = device_roof("TPU v5 lite")
+    assert roof == {"peak_flops": 197e12, "hbm_bw": 819e9}
+    for unknown in ("cpu", "Quantum FPGA 9000", ""):
+        with pytest.raises(KeyError, match="no published roof"):
+            device_roof(unknown)
+    rep = predicted_vs_measured("lloyd", 1e-3, device_kind="TPU v5 lite",
+                                m=262_144, d=128, k=1024)
+    assert rep["predicted_s"] > 0 and rep["dominant"] in ("compute",
+                                                          "memory")
+
+
 def test_lookup_rejects_bad_dims():
     with pytest.raises(ValueError, match="unknown tunable kernel"):
         autotune.lookup("warp", m=8, d=8, k=8)
@@ -433,7 +446,9 @@ def test_sweep_point_artifact_schema(tmp_path):
                     iters=1, warmup=0, save=False, out_dir=tmp_path)
     assert e["bench"] == "tune" and e["speedup_vs_default"] >= 1.0
     assert e["numerics_verified"] and e["n_candidates"] == 2
-    assert e["roofline"]["predicted_s"] > 0
+    # interpreted timings carry no roofline: the roof table knows devices
+    # only, and the CPU is none of them
+    assert e["mode"] == "interpret" and e["roofline"] is None
     on_disk = json.loads((tmp_path / "BENCH_tune_lloyd_M512_d16_K16.json")
                          .read_text())
     assert on_disk["config"] == e["config"]

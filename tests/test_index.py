@@ -392,7 +392,6 @@ def test_recall_at_k_counts_partial_overlap():
 _SHARD_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
 import jax
 import numpy as np
 from repro import compat

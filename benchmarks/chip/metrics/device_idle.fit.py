@@ -1,0 +1,9 @@
+"""One minus device busy time over the traced window, percent (mean over
+the devices the cell uses)."""
+
+
+def read(ctx):
+    t = ctx.trace
+    if t is None or t.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)

@@ -261,17 +261,14 @@ def execute(pl: ExecutionPlan, x, key: Optional[Array] = None, *,
     if pl.mode == "single":
         if pl.spec.execution.donate:
             # under jit the host-side stage timers inside fit_from_spec
-            # disable themselves (trace-time noise); time the compiled
-            # call from out here instead
+            # disable themselves (trace-time noise; its scopes stay in the
+            # program); the compiled call is the "fit" stage out here
             fit = jax.jit(fit_from_spec,
                           static_argnames=("spec", "backend"),
                           donate_argnums=0)
-            with pl.logger.timer("fit_single_donated",
-                                 n=int(x.shape[0]), k=pl.spec.merge.k):
-                res = fit(x, pl.spec, key, backend=pl.backend)
-                if pl.logger is not NULL:
-                    jax.block_until_ready(res.sse)
-            return res
+            with pl.logger.stage("fit", n=int(x.shape[0]),
+                                 k=pl.spec.merge.k) as st:
+                return st.ready(fit(x, pl.spec, key, backend=pl.backend))
         return fit_from_spec(x, pl.spec, key, backend=pl.backend,
                              logger=pl.logger)
     if pl.mode == "shard_map":
@@ -364,12 +361,21 @@ class SampledKMeans:
         resolves non-resident sources to ``chunked``, or ``chunked_dist``
         when the estimator also has a ``mesh``).  Always starts
         fresh: any live ``partial_fit`` stream state is discarded, so a
-        later ``partial_fit`` begins a new stream."""
+        later ``partial_fit`` begins a new stream.
+
+        On the profiler's host trace the call is the span
+        ``SampledKMeans.fit``, with ``plan`` and the executor's stages
+        (``fit``: the dispatch of the compiled fit) inside it."""
+        with jax.profiler.TraceAnnotation("SampledKMeans.fit"):
+            return self._fit(x, key)
+
+    def _fit(self, x, key: Optional[Array]) -> "SampledKMeans":
         src = x if isinstance(x, DataSource) else None
-        if src is not None:
-            pl = self.plan(src.shape, source=src)
-        else:
-            pl = self.plan(tuple(x.shape))
+        with jax.profiler.TraceAnnotation("plan"):
+            if src is not None:
+                pl = self.plan(src.shape, source=src)
+            else:
+                pl = self.plan(tuple(x.shape))
         self._reset_stream()    # fit is a fresh estimator state, every mode
         self.chunk_stats_ = None
         if pl.mode == "stream":

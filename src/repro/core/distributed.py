@@ -49,6 +49,7 @@ from .pipeline import (SampledClusteringResult, _CHUNK_KEY_OFFSET,
 from .metrics import sse as sse_fn
 from .spec import ClusterSpec, StopSpec
 from .subcluster import gather_partitions, get_partitioner, unscale
+from repro.telemetry import NULL, get_run_logger, peak_rss_mb, scope
 
 _now = time.perf_counter
 
@@ -103,9 +104,6 @@ def _distributed_merge(
     stride_ids = jnp.round(jnp.linspace(0, n_local - 1, n_cand)).astype(jnp.int32)
     cand = jax.lax.all_gather(local_centers[stride_ids], axis, tiled=True)
     cand_w = jax.lax.all_gather(local_w[stride_ids], axis, tiled=True)
-    first = jnp.argmax(cand_w)  # heaviest candidate
-    centers0 = jnp.zeros((k, cand.shape[-1]), cand.dtype).at[0].set(cand[first])
-    min_d = jnp.sum((cand - cand[first]) ** 2, axis=-1)
 
     # Jitter scale for the exhausted-pool fallback: when the gathered pool
     # holds fewer than k live distinct candidates, greedy selection would
@@ -128,7 +126,12 @@ def _distributed_merge(
         min_d = jnp.minimum(min_d, jnp.sum((cand - c) ** 2, axis=-1))
         return centers, min_d
 
-    centers0, _ = jax.lax.fori_loop(1, k, pick, (centers0, min_d))
+    with scope("kmeans_init"):
+        first = jnp.argmax(cand_w)  # heaviest candidate
+        centers0 = jnp.zeros((k, cand.shape[-1]),
+                             cand.dtype).at[0].set(cand[first])
+        min_d = jnp.sum((cand - cand[first]) ** 2, axis=-1)
+        centers0, _ = jax.lax.fori_loop(1, k, pick, (centers0, min_d))
 
     prep = backend.prepare(local_centers, local_w)  # pad once, not per round
 
@@ -261,21 +264,23 @@ def make_distributed_sampled_kmeans(
         key_dev = jax.random.fold_in(key_local, my)
         xn, scale_params = _global_feature_scale(xs, axis)
 
-        part = partitioner(xn, n_sub_per_device, capacity_factor)
-        parts, part_w = gather_partitions(xn, part)
-        cap = parts.shape[1]
-        k_local = max(1, cap // compression)
+        with scope("fold"):
+            part = partitioner(xn, n_sub_per_device, capacity_factor)
+            parts, part_w = gather_partitions(xn, part)
+            cap = parts.shape[1]
+            k_local = max(1, cap // compression)
 
-        keys = jax.random.split(jax.random.fold_in(key_dev, 1),
-                                n_sub_per_device)
-        local = jax.vmap(
-            lambda p, w, kk: kmeans(p, k_local, weights=w, stop=local_stop,
-                                    key=kk, init=init, backend=be)
-        )(parts, part_w, keys)
+            keys = jax.random.split(jax.random.fold_in(key_dev, 1),
+                                    n_sub_per_device)
+            local = jax.vmap(
+                lambda p, w, kk: kmeans(p, k_local, weights=w,
+                                        stop=local_stop, key=kk, init=init,
+                                        backend=be)
+            )(parts, part_w, keys)
 
-        d = xs.shape[-1]
-        lc = local.centers.reshape(n_sub_per_device * k_local, d)
-        lw = local.counts.reshape(n_sub_per_device * k_local)
+            d = xs.shape[-1]
+            lc = local.centers.reshape(n_sub_per_device * k_local, d)
+            lw = local.counts.reshape(n_sub_per_device * k_local)
 
         # Hierarchical reduce tree, all_gather-free: every extra level
         # re-partitions THIS device's weighted pool and shrinks it in
@@ -283,37 +288,40 @@ def make_distributed_sampled_kmeans(
         # (dropped mass has no channel here — build time warns on
         # unequal-scheme levels)
         for i, lvl in enumerate(levels):
-            lc, lw, _ = reduce_pool(lc, lw, lvl,
-                                    jax.random.fold_in(key_dev, 2 + i),
-                                    backend=be)
+            with scope("reduce_level"):
+                lc, lw, _ = reduce_pool(lc, lw, lvl,
+                                        jax.random.fold_in(key_dev, 2 + i),
+                                        backend=be)
 
         merge_w = lw if weighted_merge else (lw > 0).astype(xs.dtype)
 
-        if merge == "replicated":
-            # Paper-faithful: gather every local center everywhere, merge
-            # redundantly (the "host" stage, replicated instead of serial).
-            all_c = jax.lax.all_gather(lc, axis, tiled=True)
-            all_w = jax.lax.all_gather(merge_w, axis, tiled=True)
-            merged = kmeans(all_c, k, weights=all_w, stop=global_stop,
-                            key=key_merge, init=merge_init,
-                            backend=be,
-                            restarts=restarts)  # same multi-seed guard as
-                                                # the batch merge stage
-            centers = merged.centers
-        elif merge == "distributed":
-            centers, _ = _distributed_merge(lc, merge_w, k, global_stop,
-                                            key_merge, axis, be)
-            all_c = jax.lax.all_gather(lc, axis, tiled=True)
-            all_w = jax.lax.all_gather(merge_w, axis, tiled=True)
-        else:
-            raise ValueError(f"unknown merge {merge!r}")
+        with scope("merge"):
+            if merge == "replicated":
+                # Paper-faithful: gather every local center everywhere,
+                # merge redundantly (the "host" stage, replicated instead
+                # of serial), with the batch merge's multi-seed guard.
+                all_c = jax.lax.all_gather(lc, axis, tiled=True)
+                all_w = jax.lax.all_gather(merge_w, axis, tiled=True)
+                merged = kmeans(all_c, k, weights=all_w, stop=global_stop,
+                                key=key_merge, init=merge_init,
+                                backend=be, restarts=restarts)
+                centers = merged.centers
+            elif merge == "distributed":
+                centers, _ = _distributed_merge(lc, merge_w, k, global_stop,
+                                                key_merge, axis, be)
+                all_c = jax.lax.all_gather(lc, axis, tiled=True)
+                all_w = jax.lax.all_gather(merge_w, axis, tiled=True)
+            else:
+                raise ValueError(f"unknown merge {merge!r}")
 
         # global SSE in the scaled space would under-report wide features;
         # map everything back through (lo, span) and score in input space
         centers = unscale(centers, scale_params)
         all_c = unscale(all_c, scale_params)
-        local_sse = jnp.sum(jnp.min(pairwise_sqdist(xs, centers), axis=-1))
-        total_sse = jax.lax.psum(local_sse, axis)
+        with scope("sse"):
+            local_sse = jnp.sum(jnp.min(pairwise_sqdist(xs, centers),
+                                        axis=-1))
+            total_sse = jax.lax.psum(local_sse, axis)
         return DistributedClusteringResult(centers, all_c, all_w, total_sse)
 
     mapped = compat.shard_map(
@@ -326,10 +334,9 @@ def make_distributed_sampled_kmeans(
     fitted = jax.jit(mapped)
 
     # telemetry (logger= or spec.execution.telemetry): the shard_map body
-    # cannot log host-side, so the compiled fit is timed from out here —
-    # one "fit_shard_map" timer per call, with the mesh/merge accounting.
-    # Telemetry-only sync; the NULL path returns the bare jitted fn.
-    from repro.telemetry import NULL, get_run_logger
+    # cannot log host-side, so the compiled fit is one "fit_shard_map"
+    # stage from out here, with the mesh/merge accounting; the NULL path
+    # returns the bare jitted fn (the body's scopes name its stages).
     log = get_run_logger(
         logger if logger is not None
         else (spec.execution.telemetry if spec is not None else None))
@@ -339,11 +346,10 @@ def make_distributed_sampled_kmeans(
     n_dev = int(mesh.shape[axis])
 
     def logged(x, key):
-        with log.timer("fit_shard_map", n=int(x.shape[0]), k=k,
+        with log.stage("fit_shard_map", n=int(x.shape[0]), k=k,
                        merge_path=merge, levels=len(levels),
-                       devices=n_dev):
-            res = fitted(x, key)
-            jax.block_until_ready(res.sse)
+                       devices=n_dev) as st:
+            res = st.ready(fitted(x, key))
         log.event("dist_fit", n=int(x.shape[0]), k=k, merge_path=merge,
                   devices=n_dev,
                   pool=int(res.local_centers.shape[0]),
@@ -425,9 +431,12 @@ def merge_pool_distributed(pools, pool_ws, spec: ClusterSpec,
     dc = jax.device_put(all_c, sharding)
     dw = jax.device_put(merge_w, sharding)
     k, stop = spec.merge.k, spec.merge.effective_stop
+    def merge_body(lc, lw, kk):
+        with scope("merge"):
+            return _distributed_merge(lc, lw, k, stop, kk, axis, be)
+
     body = compat.shard_map(
-        lambda lc, lw, kk: _distributed_merge(lc, lw, k, stop, kk,
-                                              axis, be),
+        merge_body,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P()),
         out_specs=(P(), P()),
@@ -476,7 +485,6 @@ def fit_chunked_dist(source, spec: ClusterSpec, mesh: jax.sharding.Mesh,
     ``(SampledClusteringResult, ChunkDistStats)``.
     """
     from repro.data.source import as_source, prefetch_to_device
-    from repro.telemetry import NULL, get_run_logger, peak_rss_mb
     log = get_run_logger(logger if logger is not None
                          else spec.execution.telemetry)
     source = as_source(source)
@@ -507,7 +515,8 @@ def fit_chunked_dist(source, spec: ClusterSpec, mesh: jax.sharding.Mesh,
         # per-shard running min/max, combined on the host: min/max are
         # exact and order-independent, so this is bitwise the single-pass
         # answer no matter how the rows were sharded
-        with log.timer("scale_pass", devices=n_dev):
+        # min/max come to the host (np.asarray): the stage ends when ready
+        with log.stage("scale_pass", devices=n_dev):
             lo_parts, hi_parts = [], []
             for i, shard in enumerate(shards):
                 slo, shi = minmax_pass(shard, cp, prefetch=depth,
@@ -532,7 +541,7 @@ def fit_chunked_dist(source, spec: ClusterSpec, mesh: jax.sharding.Mesh,
         lo_d = [jax.device_put(lo_np, dv) for dv in devs]
         span_d = [jax.device_put(span_np, dv) for dv in devs]
     accs = [_PoolAccumulator(spec.levels, key_local, shard=i, backend=be,
-                             log=(log if log is not NULL else None))
+                             log=log)
             for i in range(n_dev)]
     dropped = [None] * n_dev
     dev_points = [0] * n_dev
@@ -541,7 +550,7 @@ def fit_chunked_dist(source, spec: ClusterSpec, mesh: jax.sharding.Mesh,
     dev_iters = [None] * n_dev   # per-device true Lloyd-iteration counts
     fold_budget = 0              # sum of max_iters budgets
     fold_rate = log.rate("fold_rate", units="points")
-    with log.timer("fold", devices=n_dev):
+    with log.stage("fold", devices=n_dev) as st:
         its = [iter(enumerate(prefetch_to_device(
                    shards[i].chunks(cp), depth, device=devs[i])))
                for i in range(n_dev)]
@@ -578,6 +587,8 @@ def fit_chunked_dist(source, spec: ClusterSpec, mesh: jax.sharding.Mesh,
                 dev_chunks[i] += 1
                 max_chunk = max(max_chunk, m)
                 fold_rate.tick(m, device=i, chunk=j, rows=m)
+        # each device's chunk folds add into its dev_iters entry
+        st.ready(dev_iters)
     n_points, n_chunks = sum(dev_points), sum(dev_chunks)
     if n_chunks == 0:
         raise ValueError("fit_chunked_dist: the source yielded no points")
@@ -595,11 +606,11 @@ def fit_chunked_dist(source, spec: ClusterSpec, mesh: jax.sharding.Mesh,
             dropped[i] = (dropped[i]
                           + jnp.round(accs[i].w_dropped).astype(jnp.int32))
         for jl, lvl in enumerate(spec.levels):
-            with log.timer("reduce_level", device=i, level=jl,
-                           pool_in=int(pool_i.shape[0])):
-                pool_i, w_i, wd = reduce_pool(
+            with log.stage("reduce_level", device=i, level=jl,
+                           pool_in=int(pool_i.shape[0])) as st:
+                pool_i, w_i, wd = st.ready(reduce_pool(
                     pool_i, w_i, lvl,
-                    jax.random.fold_in(shard_keys[i], 1 + jl), backend=be)
+                    jax.random.fold_in(shard_keys[i], 1 + jl), backend=be))
             dropped[i] = dropped[i] + jnp.round(wd).astype(jnp.int32)
         pools.append(np.asarray(pool_i))
         pool_ws.append(np.asarray(w_i))
@@ -614,8 +625,8 @@ def fit_chunked_dist(source, spec: ClusterSpec, mesh: jax.sharding.Mesh,
     pool = jnp.asarray(pool_np)
     pool_w = jnp.asarray(pool_w_np)
 
-    with log.timer("merge", pool=int(pool.shape[0]), k=spec.merge.k,
-                   merge_path=spec.execution.merge_path):
+    with log.stage("merge", pool=int(pool.shape[0]), k=spec.merge.k,
+                   merge_path=spec.execution.merge_path) as st:
         if spec.execution.merge_path == "distributed":
             # pools stay device-resident; one collective per Lloyd round
             # moves only the k global centers (padded rows carry 0 weight);
@@ -633,6 +644,7 @@ def fit_chunked_dist(source, spec: ClusterSpec, mesh: jax.sharding.Mesh,
             merged = merge_pool(pool, pool_w, spec.merge, key_global,
                                 backend=be)
             centers, merge_iters = merged.centers, merged.n_iter
+        st.ready(centers)
     if log is not NULL:
         _log_stage_iters(log, "fold",
                          sum(int(it) for it in dev_iters if it is not None),
@@ -647,7 +659,7 @@ def fit_chunked_dist(source, spec: ClusterSpec, mesh: jax.sharding.Mesh,
         local_centers = unscale(local_centers, params)
 
     if spec.chunk.sse == "exact":
-        with log.timer("sse_pass", devices=n_dev):
+        with log.stage("sse_pass", devices=n_dev) as st:
             totals = []
             for i, shard in enumerate(shards):
                 c_i = jax.device_put(centers, devs[i])
@@ -656,14 +668,15 @@ def fit_chunked_dist(source, spec: ClusterSpec, mesh: jax.sharding.Mesh,
                     totals.append(s)
             # 1 device: the untouched device total — bitwise fit_chunked;
             # n devices: host-order sum of per-shard partials
-            total_sse = (totals[0] if len(totals) == 1
-                         else jnp.asarray(sum(float(s) for s in totals),
-                                          jnp.float32))
+            total_sse = st.ready(
+                totals[0] if len(totals) == 1
+                else jnp.asarray(sum(float(s) for s in totals), jnp.float32))
         passes += 1
         log.event("pass_rss", stage="sse", peak_rss_mb=peak_rss_mb())
     else:  # "pool": weighted SSE of the representatives, no extra pass
-        with log.timer("sse_pool"):
-            total_sse = sse_fn(local_centers, centers, weights=pool_w)
+        with log.stage("sse_pool") as st:
+            total_sse = st.ready(sse_fn(local_centers, centers,
+                                        weights=pool_w))
 
     result = SampledClusteringResult(centers, total_sse, local_centers,
                                      pool_w, n_dropped)
@@ -675,9 +688,7 @@ def fit_chunked_dist(source, spec: ClusterSpec, mesh: jax.sharding.Mesh,
                            per_device_chunks=tuple(dev_chunks),
                            peak_pool_rows=peak_pool)
     if log is not NULL:
-        jax.block_until_ready(total_sse)   # telemetry-only sync: wall
-        #                                    times mean "result ready"
-        wall = _now() - t_start
+        wall = _now() - t_start   # the stages waited: "result ready"
         summary = stats._asdict()
         summary["per_device_points"] = list(stats.per_device_points)
         summary["per_device_chunks"] = list(stats.per_device_chunks)

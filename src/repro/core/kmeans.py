@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from .backend import BackendSpec, LloydBackend, AssignFnBackend, get_backend
 from .metrics import HIGHEST, nearest
 from .spec import StopSpec
+from repro.telemetry import scope
 
 Array = jax.Array
 
@@ -434,10 +435,13 @@ def kmeans(
         return centers, idx, sse, n_iter
 
     def one_run(kk, r):
-        if isinstance(init, str):
-            centers0 = get_init(init)(x, weights, k, kk)
-        else:
-            centers0 = _jittered_array_init(init, x, kk, r)
+        # every seeding (k-means++, k-means||, random, an array's jitter)
+        # under one name, in the fold's lanes and the merge's restarts
+        with scope("kmeans_init"):
+            if isinstance(init, str):
+                centers0 = get_init(init)(x, weights, k, kk)
+            else:
+                centers0 = _jittered_array_init(init, x, kk, r)
         return lloyd(centers0, kk)
 
     if restarts <= 1:

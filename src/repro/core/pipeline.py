@@ -42,6 +42,7 @@ from .metrics import sse as sse_fn
 from .spec import ClusterSpec, LevelSpec, MergeSpec, StopSpec
 from .subcluster import (Partition, feature_scale, gather_partitions,
                          get_partitioner, unscale)
+from repro.telemetry import NULL, get_run_logger, peak_rss_mb, scope
 
 Array = jax.Array
 
@@ -121,18 +122,22 @@ def chunk_fold(xs: Array, lv: LevelSpec, key: Array, *,
     ``lv.effective_stop``.
     """
     be = get_backend(backend)
-    part: Partition = get_partitioner(lv.scheme)(xs, lv.n_sub,
-                                                 lv.capacity_factor)
-    parts, part_w = gather_partitions(xs, part)
-    cap = parts.shape[1]
-    k_local = max(1, cap // lv.compression)
-    local = local_stage(parts, part_w, k_local, key=key, init=lv.init,
-                        backend=be, stop=lv.effective_stop)
-    d = xs.shape[-1]
-    return (local.centers.reshape(lv.n_sub * k_local, d),
-            local.counts.reshape(lv.n_sub * k_local),
-            part.n_dropped,
-            jnp.sum(local.n_iter).astype(jnp.int32))
+    # the "fold" scope is named here, inside the jit of
+    # _fold_scaled_chunk, so the executors that dispatch that jit on its
+    # own (chunked, chunked_dist) name it in the device trace too
+    with scope("fold"):
+        part: Partition = get_partitioner(lv.scheme)(xs, lv.n_sub,
+                                                     lv.capacity_factor)
+        parts, part_w = gather_partitions(xs, part)
+        cap = parts.shape[1]
+        k_local = max(1, cap // lv.compression)
+        local = local_stage(parts, part_w, k_local, key=key, init=lv.init,
+                            backend=be, stop=lv.effective_stop)
+        d = xs.shape[-1]
+        return (local.centers.reshape(lv.n_sub * k_local, d),
+                local.counts.reshape(lv.n_sub * k_local),
+                part.n_dropped,
+                jnp.sum(local.n_iter).astype(jnp.int32))
 
 
 def merge_pool(pool: Array, pool_w: Array, merge: MergeSpec, key: Array, *,
@@ -211,13 +216,14 @@ def fit_from_spec(x: Array, spec: ClusterSpec,
     already resolved an instance; ``logger`` likewise overrides
     ``spec.execution.telemetry`` (a resolved :class:`RunLogger`).
 
-    Telemetry is strictly host-side (timers around stage dispatch), so a
-    logged fit is bit-for-bit the unlogged fit.  When this function is
-    itself traced under ``jax.jit`` (the ``donate`` path, perf harnesses),
-    host timers would fire once at trace time and mean nothing — the
-    logger is disabled in that case and the *caller* times the compiled
-    call instead."""
-    from repro.telemetry import NULL, get_run_logger
+    The stages ``fold``, ``reduce_level``, ``merge`` and ``sse`` are
+    logger stages (:meth:`repro.telemetry.RunLogger.stage`): named scopes
+    in the device trace always; with a logger, timers that wait for each
+    stage's outputs.  A logged fit is bit-for-bit the unlogged fit.  When
+    this function is itself traced under ``jax.jit`` (the ``donate``
+    path, perf harnesses), host timers would fire once at trace time and
+    mean nothing — the logger is disabled in that case (the scopes stay)
+    and the *caller* times the compiled call as its ``fit`` stage."""
     if isinstance(x, jax.core.Tracer):
         log = NULL    # tracing: host-side timers would measure the trace
     else:
@@ -244,9 +250,9 @@ def fit_from_spec(x: Array, spec: ClusterSpec,
     # parity pin holds by construction (for every dtype: sharing the trace
     # sidesteps jit-vs-eager bf16 rounding differences)
     base = spec.level_schedule()[0]
-    with log.timer("fold", rows=int(x.shape[0])):
-        local_centers, local_counts, n_dropped, fold_iters = \
-            _fold_scaled_chunk(x, lo, span, key_local, lv=base, backend=be)
+    with log.stage("fold", rows=int(x.shape[0])) as st:
+        local_centers, local_counts, n_dropped, fold_iters = st.ready(
+            _fold_scaled_chunk(x, lo, span, key_local, lv=base, backend=be))
     if log is not NULL:
         _log_stage_iters(log, "fold", int(fold_iters),
                          base.effective_stop.max_iters * base.n_sub)
@@ -255,20 +261,20 @@ def fit_from_spec(x: Array, spec: ClusterSpec,
     # pool until it is small enough for the merge stage (spec.levels is ()
     # for the paper's flat two-level pipeline — the loop is a no-op there)
     for i, lvl in enumerate(spec.levels):
-        with log.timer("reduce_level", level=i,
-                       pool_in=int(local_centers.shape[0])):
-            local_centers, local_counts, w_dropped = reduce_pool(
+        with log.stage("reduce_level", level=i,
+                       pool_in=int(local_centers.shape[0])) as st:
+            local_centers, local_counts, w_dropped = st.ready(reduce_pool(
                 local_centers, local_counts, lvl,
-                jax.random.fold_in(key_local, 1 + i), backend=be)
+                jax.random.fold_in(key_local, 1 + i), backend=be))
         # unequal-scheme levels can clamp overflow ENTRIES; each carries
         # the mass of the original points it represents — keep the loss
         # visible in the same n_dropped channel as the base partition
         n_dropped = n_dropped + jnp.round(w_dropped).astype(jnp.int32)
 
-    with log.timer("merge", pool=int(local_centers.shape[0]),
-                   k=spec.merge.k):
-        merged = merge_pool(local_centers, local_counts, spec.merge,
-                            key_global, backend=be)
+    with log.stage("merge", pool=int(local_centers.shape[0]),
+                   k=spec.merge.k) as st:
+        merged = st.ready(merge_pool(local_centers, local_counts,
+                                     spec.merge, key_global, backend=be))
     if log is not NULL:
         _log_stage_iters(log, "merge", int(merged.n_iter),
                          spec.merge.effective_stop.max_iters)
@@ -277,12 +283,10 @@ def fit_from_spec(x: Array, spec: ClusterSpec,
     if spec.scale:
         centers = unscale(centers, params)
         local_centers = unscale(local_centers, params)
-    with log.timer("sse"):
-        total_sse = sse_fn(x, centers)
+    with log.stage("sse") as st:
+        total_sse = st.ready(sse_fn(x, centers))
     if log is not NULL:
-        jax.block_until_ready(total_sse)   # telemetry-only sync: wall
-        #                                    times mean "result ready"
-        wall = _now() - t_start
+        wall = _now() - t_start   # the stages waited: "result ready"
         log.event("fit_from_spec", n=int(x.shape[0]), d=d, k=spec.merge.k,
                   levels=spec.n_levels, backend=be.name, wall_s=wall,
                   points_per_sec=int(x.shape[0]) / max(wall, 1e-9))
@@ -375,7 +379,7 @@ class _PoolAccumulator:
     per-level streams."""
 
     def __init__(self, levels, key_local: Array, *, shard: int = 0,
-                 backend: BackendSpec = None, log=None):
+                 backend: BackendSpec = None, log=NULL):
         from repro.core.spec import CHUNK_FOLD_BUFFER
         self._level = levels[0] if levels else None
         self._buffer = CHUNK_FOLD_BUFFER
@@ -412,12 +416,10 @@ class _PoolAccumulator:
         pool, pool_w = self._concat()
         rows_in = int(pool.shape[0])
         key = jax.random.fold_in(self._key_flush, self.n_flushes)
-        ctx = (self._log.timer("pool_flush", flush=self.n_flushes,
-                               rows_in=rows_in)
-               if self._log is not None else _null_ctx())
-        with ctx:
-            pool, pool_w, wd = reduce_pool(pool, pool_w, self._level, key,
-                                           backend=self._backend)
+        with self._log.stage("pool_flush", flush=self.n_flushes,
+                             rows_in=rows_in) as st:
+            pool, pool_w, wd = st.ready(reduce_pool(
+                pool, pool_w, self._level, key, backend=self._backend))
         self.w_dropped = wd if self.w_dropped is None else self.w_dropped + wd
         self._pools, self._ws = [pool], [pool_w]
         self._rows = int(pool.shape[0])
@@ -430,14 +432,6 @@ class _PoolAccumulator:
         if not self._pools:
             raise ValueError("fold accumulator: no chunk pools were added")
         return self._concat()
-
-
-class _null_ctx:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
 
 
 @functools.partial(jax.jit, static_argnames=("lv", "backend"))
@@ -483,14 +477,14 @@ def fit_chunked(source, spec: ClusterSpec, key: Optional[Array] = None, *,
     the same functions).  Returns ``(result, ChunkStats)``.
 
     With a logger (``logger=`` or ``spec.execution.telemetry``) the run
-    emits per-stage timers, a per-chunk ``fold_rate`` series
+    emits per-stage timers that wait for their stage's outputs, a
+    per-chunk ``fold_rate`` series
     (median-window points/sec — one slow tick, e.g. the compile on chunk
     0, does not read as the steady-state rate), and a final summary event
     carrying the :class:`ChunkStats` accounting plus peak RSS.  All of it
     host-side: the logged fit is bit-for-bit the unlogged fit.
     """
     from repro.data.source import as_source, prefetch_to_device
-    from repro.telemetry import NULL, get_run_logger, peak_rss_mb
     log = get_run_logger(logger if logger is not None
                          else spec.execution.telemetry)
     source = as_source(source)
@@ -507,18 +501,18 @@ def fit_chunked(source, spec: ClusterSpec, key: Optional[Array] = None, *,
     passes = 1
     lo = span = None
     if spec.scale:
-        with log.timer("scale_pass"):
-            lo, span = scale_pass(source, cp, prefetch=depth)
+        with log.stage("scale_pass") as st:
+            lo, span = st.ready(scale_pass(source, cp, prefetch=depth))
         passes += 1
 
     acc = _PoolAccumulator(spec.levels, key_local, shard=0, backend=be,
-                           log=(log if log is not NULL else None))
+                           log=log)
     n_dropped = jnp.asarray(0, jnp.int32)
     fold_iters = jnp.asarray(0, jnp.int32)   # true Lloyd-iteration count
     fold_budget = 0                          # sum of max_iters budgets
     n_points = n_chunks = max_chunk = 0
     fold_rate = log.rate("fold_rate", units="points")
-    with log.timer("fold"):
+    with log.stage("fold") as st:
         for i, chunk in enumerate(prefetch_to_device(source.chunks(cp),
                                                      depth)):
             m, d = chunk.shape
@@ -541,6 +535,8 @@ def fit_chunked(source, spec: ClusterSpec, key: Optional[Array] = None, *,
             n_chunks += 1
             max_chunk = max(max_chunk, m)
             fold_rate.tick(m, chunk=i, rows=m)
+        # every chunk's fold adds into fold_iters: ready once all are done
+        st.ready(fold_iters)
     if n_chunks == 0:
         raise ValueError("fit_chunked: the source yielded no points")
 
@@ -549,14 +545,17 @@ def fit_chunked(source, spec: ClusterSpec, key: Optional[Array] = None, *,
         n_dropped = n_dropped + jnp.round(acc.w_dropped).astype(jnp.int32)
 
     for j, lvl in enumerate(spec.levels):
-        with log.timer("reduce_level", level=j, pool_in=int(pool.shape[0])):
-            pool, pool_w, w_dropped = reduce_pool(
+        with log.stage("reduce_level", level=j,
+                       pool_in=int(pool.shape[0])) as st:
+            pool, pool_w, w_dropped = st.ready(reduce_pool(
                 pool, pool_w, lvl, jax.random.fold_in(key_local, 1 + j),
-                backend=be)
+                backend=be))
         n_dropped = n_dropped + jnp.round(w_dropped).astype(jnp.int32)
 
-    with log.timer("merge", pool=int(pool.shape[0]), k=spec.merge.k):
-        merged = merge_pool(pool, pool_w, spec.merge, key_global, backend=be)
+    with log.stage("merge", pool=int(pool.shape[0]),
+                   k=spec.merge.k) as st:
+        merged = st.ready(merge_pool(pool, pool_w, spec.merge, key_global,
+                                     backend=be))
     if log is not NULL:
         _log_stage_iters(log, "fold", int(fold_iters), fold_budget)
         _log_stage_iters(log, "merge", int(merged.n_iter),
@@ -568,12 +567,14 @@ def fit_chunked(source, spec: ClusterSpec, key: Optional[Array] = None, *,
         local_centers = unscale(local_centers, (lo, span))
 
     if spec.chunk.sse == "exact":
-        with log.timer("sse_pass"):
-            total_sse = sse_pass(source, centers, cp, prefetch=depth)
+        with log.stage("sse_pass") as st:
+            total_sse = st.ready(sse_pass(source, centers, cp,
+                                          prefetch=depth))
         passes += 1
     else:  # "pool": weighted SSE of the representatives, no extra pass
-        with log.timer("sse_pool"):
-            total_sse = sse_fn(local_centers, centers, weights=pool_w)
+        with log.stage("sse_pool") as st:
+            total_sse = st.ready(sse_fn(local_centers, centers,
+                                        weights=pool_w))
 
     result = SampledClusteringResult(centers, total_sse, local_centers,
                                      pool_w, n_dropped)
@@ -582,9 +583,7 @@ def fit_chunked(source, spec: ClusterSpec, key: Optional[Array] = None, *,
                        pool_size=int(pool.shape[0]), prefetch=depth,
                        passes=passes, peak_pool_rows=acc.peak_rows)
     if log is not NULL:
-        jax.block_until_ready(total_sse)   # telemetry-only sync: wall
-        #                                    times mean "result ready"
-        wall = _now() - t_start
+        wall = _now() - t_start   # the stages waited: "result ready"
         log.event("fit_chunked", k=spec.merge.k, levels=spec.n_levels,
                   backend=be.name, wall_s=wall,
                   points_per_sec=n_points / max(wall, 1e-9),

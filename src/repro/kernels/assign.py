@@ -116,5 +116,6 @@ def assign_argmin_pallas(
         ],
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="assign",
     )(x, c)
     return idx[0], dist[0]

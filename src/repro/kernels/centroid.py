@@ -86,5 +86,6 @@ def centroid_update_pallas(
         ],
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="centroid",
     )(x, idx.reshape(1, m), w.reshape(1, m))
     return sums, counts[:, 0]

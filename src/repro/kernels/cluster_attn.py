@@ -102,6 +102,7 @@ def cluster_attn_decode_pallas(
             jax.ShapeDtypeStruct((b, hkv, g), jnp.float32),
         ],
         interpret=interpret,
+        name="cluster_attn",
     )(qg, kc, vc, counts)
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     return out.reshape(b, h, dh)

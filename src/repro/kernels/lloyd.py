@@ -148,5 +148,6 @@ def lloyd_step_pallas(
         ],
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="lloyd",
     )(x, w.reshape(1, m), c)
     return sums[:k], counts[:k, 0], sse[0, 0], idx[0], dist[0]

@@ -114,6 +114,7 @@ def adc_scan_pallas(luts: jax.Array, codes: jax.Array, *,
         out_specs=pl.BlockSpec((1, 1, block_l), lambda i, j: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((b, 1, lp), jnp.float32),
         interpret=interpret,
+        name="scan",
     )(codes, luts)
     return out[:, 0, :l]
 

@@ -4,9 +4,10 @@ machine-speed calibration probe) the benchmark/gate layer shares.
 
 Design constraints, in order:
 
-  1. **Zero interference.**  Telemetry is strictly host-side: it never
-     touches PRNG keys, array values, or trace structure, so a fit with a
-     logger attached is bit-for-bit the fit without one (pinned by
+  1. **Zero interference.**  Telemetry never touches PRNG keys, array
+     values, or trace structure (a stage's named scope only labels the
+     operations traced in it), so a fit with a logger attached is
+     bit-for-bit the fit without one (pinned by
      ``tests/test_telemetry.py``).  The default :data:`NULL` logger reduces
      every call to a constant no-op so un-instrumented runs pay ~nothing.
   2. **One schema.**  Every emission is a plain dict that round-trips
@@ -17,6 +18,16 @@ Design constraints, in order:
      (compilation, prefetch stalls, GC); throughput is reported as the
      median over a sliding window of recent steps — the wandblog idiom —
      so one slow tick does not masquerade as a regression.
+
+Stages.  :meth:`RunLogger.stage` is the one way the program names a span
+of device work: the same name is a ``jax.named_scope`` (the operations
+traced inside carry it in their HLO ``op_name``, so a profiler trace finds
+them), a ``jax.profiler.TraceAnnotation`` host span on the profiler's
+clock when the code runs outside a trace, and, with a logger attached, a
+``timer`` that waits for the stage's outputs (:meth:`Stage.ready`) before
+it closes, so its ``dur`` is the time to a ready result and not the
+enqueue.  :func:`scope` is the stage without a logger, for code that has
+none (``kmeans_init`` in :mod:`repro.core.kmeans`).
 
 Loggers resolve through a registry (``"off"``, ``"memory"``,
 ``"jsonl[:path]"`` built in; :func:`register_run_logger` adds more), which
@@ -30,6 +41,8 @@ import contextlib
 import json
 import time
 from typing import Callable, Iterable, Optional
+
+import jax
 
 SCHEMA_VERSION = 1
 EVENT_KINDS = ("event", "timer", "rate")
@@ -140,6 +153,50 @@ class RateMeter:
         return self._steps
 
 
+class Stage:
+    """What ``with log.stage(name) as st`` and ``with scope(name) as st``
+    hold open: the named scope, and the host span outside a trace.
+    ``st.ready(outputs)`` hands the stage its outputs and returns them;
+    a logged stage waits for them before its timer closes, an unlogged one
+    never does."""
+    __slots__ = ("_scope", "_span", "outputs")
+
+    def __init__(self, name: str):
+        self._scope = jax.named_scope(name)
+        # inside a trace the host code runs once, at trace time: no span
+        self._span = (jax.profiler.TraceAnnotation(name)
+                      if jax.core.trace_ctx.is_top_level() else None)
+        self.outputs = None
+
+    def __enter__(self) -> "Stage":
+        self._scope.__enter__()
+        if self._span is not None:
+            self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        if self._span is not None:
+            self._span.__exit__(*exc)
+        return self._scope.__exit__(*exc)
+
+    def ready(self, outputs):
+        return outputs
+
+
+class _TimedStage(Stage):
+    __slots__ = ()
+
+    def ready(self, outputs):
+        self.outputs = outputs
+        return outputs
+
+
+def scope(name: str) -> Stage:
+    """The stage ``name`` with no logger: the named scope, and the host
+    span outside a trace; ``ready`` never syncs."""
+    return Stage(name)
+
+
 class RunLogger:
     """Structured run logger: ``event``/``timer``/``rate`` emissions with
     timer nesting.  Subclasses implement ``_emit(event_dict)``; everything
@@ -183,6 +240,23 @@ class RunLogger:
             self._emit(self._make("timer", name,
                                   dur=time.perf_counter() - start, **fields))
 
+    @contextlib.contextmanager
+    def stage(self, name: str, **fields):
+        """A stage of device work: :func:`scope` plus a ``timer`` of the
+        same name that, once the block hands over its outputs
+        (``st.ready(...)``), waits for them before it closes."""
+        start = time.perf_counter()
+        self._stack.append(name)
+        try:
+            with _TimedStage(name) as st:
+                yield st
+                if st.outputs is not None:
+                    jax.block_until_ready(st.outputs)
+        finally:
+            self._stack.pop()
+            self._emit(self._make("timer", name,
+                                  dur=time.perf_counter() - start, **fields))
+
     def rate(self, name: str, *, units: str = "points",
              window: int = 32) -> RateMeter:
         return RateMeter(self, name, units=units, window=window)
@@ -191,7 +265,8 @@ class RunLogger:
 class NullLogger(RunLogger):
     """The default: every call is a constant no-op.  ``timer`` returns a
     shared null context so instrumented hot loops cost one attribute lookup
-    when telemetry is off."""
+    when telemetry is off; ``stage`` is :func:`scope`: the named scope and
+    the host span, no event and no sync."""
 
     def _emit(self, event: dict) -> None:
         pass
@@ -201,6 +276,9 @@ class NullLogger(RunLogger):
 
     def timer(self, name: str, **fields):
         return contextlib.nullcontext(self)
+
+    def stage(self, name: str, **fields) -> Stage:
+        return Stage(name)
 
     def rate(self, name: str, *, units: str = "points",
              window: int = 32) -> RateMeter:

@@ -180,6 +180,167 @@ def test_telemetry_via_spec_string_and_api(blob_data):
                                   np.asarray(est2.centers_))
 
 
+# ------------------------------------------------------------ stages ----
+
+def _scope_names(op_name: str) -> set:
+    """The scopes of one HLO ``op_name``: its path components, with JAX's
+    transform wrappers (``vmap(kmeans_init)``) taken off."""
+    import re
+    return {re.sub(r"^(\w+\()+|\)+$", "", c) for c in op_name.split("/")}
+
+
+def _donated_fit_op_names(x, spec):
+    import re
+    from repro.core import fit_from_spec
+    fit = jax.jit(fit_from_spec, static_argnames=("spec", "backend"),
+                  donate_argnums=0)
+    text = fit.lower(x, spec, jax.random.PRNGKey(0)).compile().as_text()
+    return set(re.findall(r'op_name="([^"]*)"', text))
+
+
+def test_stage_logged_times_to_ready_and_null_passes_through():
+    rec = RecordingLogger()
+    y = jnp.arange(4.0)
+    with rec.stage("work", rows=4) as st:
+        out = st.ready(y * 2)
+    assert out is st.outputs
+    (ev,) = rec.events
+    assert ev["kind"] == "timer" and ev["name"] == "work"
+    assert ev["rows"] == 4 and ev["dur"] >= 0 and ev["path"] == "work"
+    with NULL.stage("work", rows=4) as st:
+        assert st.ready(y) is y
+        assert st.outputs is None          # NULL never holds outputs
+    from repro.telemetry import scope
+    with scope("work") as st:
+        assert st.ready(y) is y
+
+
+def test_stage_names_the_traced_ops_and_opens_no_span_in_a_trace():
+    from repro.telemetry import Stage
+
+    def f(v):
+        with NULL.stage("outer") as st:
+            assert st._span is None        # host code runs once, here
+            return v * 3
+    text = jax.jit(f).lower(jnp.ones(3)).compile().as_text()
+    assert 'op_name="jit(f)/outer/mul"' in text
+    assert Stage("top")._span is not None  # eager: a host span
+
+
+def test_donated_fit_program_carries_the_stage_scopes(blob_data):
+    """The compiled donate-path fit names fold, kmeans_init, merge and sse
+    in its ops' ``op_name``; seeding sits under ``kmeans_init`` in the
+    fold's partitions and in the merge's restarts alike."""
+    x = jnp.asarray(blob_data[0])
+    names = _donated_fit_op_names(x, _spec())
+    scopes = [_scope_names(n) for n in names]
+    for stage in ("fold", "kmeans_init", "merge", "sse"):
+        assert any(stage in s for s in scopes), stage
+    assert any({"fold", "kmeans_init"} <= s and "merge" not in s
+               for s in scopes)
+    assert any({"merge", "kmeans_init"} <= s and "fold" not in s
+               for s in scopes)
+    # the fold stays the jit the accepted fold_ms.fit reader matches
+    assert any("jit(_fold_scaled_chunk)" in n and "fold" in _scope_names(n)
+               for n in names)
+
+
+def test_null_fit_stages_never_sync(blob_data, monkeypatch):
+    from repro.core import fit_from_spec
+    x = jnp.asarray(blob_data[0])
+    calls = []
+    real = jax.block_until_ready
+
+    def counting(v):
+        calls.append(1)
+        return real(v)
+    monkeypatch.setattr(jax, "block_until_ready", counting)
+    res = fit_from_spec(x, _spec(), jax.random.PRNGKey(1))
+    assert not calls
+    real(res)
+    fit_from_spec(x, _spec(), jax.random.PRNGKey(1),
+                  logger=RecordingLogger())
+    assert len(calls) == 3                 # fold, merge, sse wait
+
+
+def test_logged_stages_cover_the_eager_fit(blob_data):
+    """The stage timers wait for their work: fold + merge + sse account
+    for most of the fit's wall time (they timed the enqueue before)."""
+    from repro.core import fit_from_spec
+    x = jnp.asarray(blob_data[0])
+    key = jax.random.PRNGKey(2)
+    fit_from_spec(x, _spec(), key, logger=RecordingLogger())   # compiles
+    rec = RecordingLogger()
+    fit_from_spec(x, _spec(), key, logger=rec)
+    dur = {e["name"]: e["dur"] for e in rec.events if e["kind"] == "timer"}
+    wall = rec.named("fit_from_spec")[0]["wall_s"]
+    assert set(dur) == {"fold", "merge", "sse"}
+    assert dur["fold"] + dur["merge"] + dur["sse"] >= 0.8 * wall
+
+
+def test_donated_fit_is_one_fit_stage(blob_data):
+    from repro.api import SampledKMeans
+    from repro.core.spec import ExecutionSpec
+    x = jnp.asarray(blob_data[0])
+    spec = _spec().replace(execution=ExecutionSpec(donate=True))
+    rec = RecordingLogger()
+    est = SampledKMeans(spec, logger=rec).fit(jnp.copy(x),
+                                              key=jax.random.PRNGKey(0))
+    (ev,) = rec.events
+    assert ev["name"] == "fit" and ev["kind"] == "timer"
+    assert ev["n"] == x.shape[0] and ev["k"] == spec.merge.k
+    plain = SampledKMeans(spec).fit(jnp.copy(x), key=jax.random.PRNGKey(0))
+    np.testing.assert_array_equal(np.asarray(est.centers_),
+                                  np.asarray(plain.centers_))
+
+
+def _kernel_calls():
+    from repro.kernels.assign import assign_argmin_pallas
+    from repro.kernels.centroid import centroid_update_pallas
+    from repro.kernels.cluster_attn import cluster_attn_decode_pallas
+    from repro.kernels.lloyd import lloyd_step_pallas
+    from repro.kernels.scan import adc_scan_pallas
+    x = jnp.ones((256, 8), jnp.float32)
+    w = jnp.ones((256,), jnp.float32)
+    c = jnp.ones((16, 8), jnp.float32)
+    idx = jnp.zeros((256,), jnp.int32)
+    q = jnp.ones((1, 4, 32), jnp.float32)
+    kv = jnp.ones((1, 1, 64, 32), jnp.float32)
+    return {
+        "lloyd": lambda: lloyd_step_pallas(x, w, c),
+        "assign": lambda: assign_argmin_pallas(x, c),
+        "centroid": lambda: centroid_update_pallas(x, idx, w, 16,
+                                                   block_m=256),
+        "scan": lambda: adc_scan_pallas(jnp.ones((2, 4, 16), jnp.float32),
+                                        jnp.zeros((2, 128, 4), jnp.int32)),
+        "cluster_attn": lambda: cluster_attn_decode_pallas(
+            q, kv, kv, jnp.ones((1, 1, 64), jnp.float32), 0.5),
+    }
+
+
+@pytest.mark.parametrize("kernel", ["lloyd", "assign", "centroid", "scan",
+                                    "cluster_attn"])
+def test_every_pallas_call_is_named(kernel):
+    jaxpr = jax.make_jaxpr(_kernel_calls()[kernel])()
+    names = [e.params["name"] for e in jaxpr.jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert names == [kernel]
+
+
+def test_no_unnamed_pallas_call_in_the_kernels():
+    import ast
+    kernels = REPO / "src" / "repro" / "kernels"
+    calls = 0
+    for path in sorted(kernels.glob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == "pallas_call"):
+                calls += 1
+                assert "name" in {k.arg for k in node.keywords}, path.name
+    assert calls == len(_kernel_calls())
+
+
 def test_stream_tick_telemetry(blob_data):
     from repro.stream.engine import StreamConfig, StreamingClusterer
     rec = RecordingLogger()

@@ -14,6 +14,7 @@ the gathered local centers.  Everything is static-shape / jit / vmap friendly:
 """
 from __future__ import annotations
 
+import functools
 import warnings
 from typing import Callable, NamedTuple, Optional
 
@@ -23,6 +24,7 @@ import jax.numpy as jnp
 from .backend import BackendSpec, LloydBackend, AssignFnBackend, get_backend
 from .metrics import HIGHEST, nearest
 from .spec import StopSpec
+from repro.kernels.tiles import LANE
 from repro.telemetry import scope
 
 Array = jax.Array
@@ -122,12 +124,38 @@ def landmark_init(x: Array, weights: Array, k: int, key: Array | None = None) ->
 
 
 def kmeans_pp_init(x: Array, weights: Array, k: int, key: Array) -> Array:
-    """k-means++ (D^2 weighting), incremental min-distance bookkeeping."""
-    m = x.shape[0]
+    """k-means++ (D^2 weighting), incremental min-distance bookkeeping.
+
+    Below the lane width the D^2 update reads one dense ``(m,)`` slab per
+    coordinate: row-major ``(m, d)`` points pad d to ``LANE`` lanes, and
+    each of the k-1 dependent steps would read that padding (a ``(d, m)``
+    transpose alone is laid back onto the lanes by XLA:TPU's layout
+    assignment).  Only the layout changes: the draws are the row-major
+    path's, and the squares are summed in coordinate order.
+    """
+    if x.shape[-1] < LANE:
+        with scope("kmeans_pp_lanes"):
+            cols = [x[:, j] for j in range(x.shape[-1])]
+            return _kmeans_pp(
+                weights, k, key, x.dtype,
+                point=lambda i: jnp.stack([col[i] for col in cols]),
+                sqdist_to=lambda c: functools.reduce(
+                    jnp.add, [(col - c[j]) ** 2 for j, col in enumerate(cols)]))
+    return _kmeans_pp(
+        weights, k, key, x.dtype, point=lambda i: x[i],
+        sqdist_to=lambda c: jnp.sum((x - c) ** 2, axis=-1))
+
+
+def _kmeans_pp(weights: Array, k: int, key: Array, dtype,
+               point: Callable[[Array], Array],
+               sqdist_to: Callable[[Array], Array]) -> Array:
+    """The k-means++ draws over points seen through ``point(i)`` (row i)
+    and ``sqdist_to(c)`` (every point's squared distance to ``c``)."""
     key0, key_loop = jax.random.split(key)
     first = jax.random.categorical(key0, jnp.where(weights > 0, 0.0, -jnp.inf))
-    centers0 = jnp.zeros((k,) + x.shape[1:], x.dtype).at[0].set(x[first])
-    d0 = jnp.sum((x - x[first]) ** 2, axis=-1)
+    c0 = point(first)
+    centers0 = jnp.zeros((k,) + c0.shape, dtype).at[0].set(c0)
+    d0 = sqdist_to(c0)
 
     def body(i, carry):
         centers, min_d = carry
@@ -138,9 +166,9 @@ def kmeans_pp_init(x: Array, weights: Array, k: int, key: Array) -> Array:
         logits = jnp.where(jnp.all(~jnp.isfinite(logits)),
                            jnp.where(weights > 0, 0.0, -jnp.inf), logits)
         nxt = jax.random.categorical(kk, logits)
-        c = x[nxt]
+        c = point(nxt)
         centers = centers.at[i].set(c)
-        min_d = jnp.minimum(min_d, jnp.sum((x - c) ** 2, axis=-1))
+        min_d = jnp.minimum(min_d, sqdist_to(c))
         return centers, min_d
 
     centers, _ = jax.lax.fori_loop(1, k, body, (centers0, d0))

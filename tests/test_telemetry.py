@@ -182,11 +182,16 @@ def test_telemetry_via_spec_string_and_api(blob_data):
 
 # ------------------------------------------------------------ stages ----
 
-def _scope_names(op_name: str) -> set:
-    """The scopes of one HLO ``op_name``: its path components, with JAX's
-    transform wrappers (``vmap(kmeans_init)``) taken off."""
+def _scope_path(op_name: str) -> list:
+    """The scopes of one HLO ``op_name``, outermost first: its path
+    components, with JAX's transform wrappers (``vmap(kmeans_init)``)
+    taken off."""
     import re
-    return {re.sub(r"^(\w+\()+|\)+$", "", c) for c in op_name.split("/")}
+    return [re.sub(r"^(\w+\()+|\)+$", "", c) for c in op_name.split("/")]
+
+
+def _scope_names(op_name: str) -> set:
+    return set(_scope_path(op_name))
 
 
 def _donated_fit_op_names(x, spec):
@@ -243,6 +248,20 @@ def test_donated_fit_program_carries_the_stage_scopes(blob_data):
     # the fold stays the jit the accepted fold_ms.fit reader matches
     assert any("jit(_fold_scaled_chunk)" in n and "fold" in _scope_names(n)
                for n in names)
+
+
+def test_donated_fit_program_names_the_lane_path_under_kmeans_init(
+        blob_data):
+    """At d=2 the k-means++ D^2 update runs coordinate-major under
+    ``kmeans_pp_lanes``, nested in ``kmeans_init`` (which ``seed_ms.fit``
+    reads), in the fold and in the merge."""
+    x = jnp.asarray(blob_data[0][:, :2])
+    paths = [_scope_path(n) for n in _donated_fit_op_names(x, _spec())]
+    lanes = [p for p in paths if "kmeans_pp_lanes" in p]
+    assert all("kmeans_init" in p[:p.index("kmeans_pp_lanes")]
+               for p in lanes)
+    assert any("fold" in p and "merge" not in p for p in lanes)
+    assert any("merge" in p and "fold" not in p for p in lanes)
 
 
 def test_null_fit_stages_never_sync(blob_data, monkeypatch):

@@ -93,6 +93,9 @@ def _distributed_merge(
     the same trip count and the collective schedule stays in lockstep.
     (``stop.minibatch`` does not apply here; the replicated merge path
     supports it.)  Returns ``(centers, n_iter)``, both replicated.
+
+    The seeding is always the greedy farthest-point pick below, one run:
+    a spec's ``merge.init`` and ``merge.restarts`` do not apply here.
     """
     # Replicated init: gather a candidate pool and run greedy farthest-point
     # (k-center) selection — identical on every device (the key is
@@ -288,10 +291,9 @@ def make_distributed_sampled_kmeans(
         # (dropped mass has no channel here — build time warns on
         # unequal-scheme levels)
         for i, lvl in enumerate(levels):
-            with scope("reduce_level"):
-                lc, lw, _ = reduce_pool(lc, lw, lvl,
-                                        jax.random.fold_in(key_dev, 2 + i),
-                                        backend=be)
+            lc, lw, _ = reduce_pool(lc, lw, lvl,
+                                    jax.random.fold_in(key_dev, 2 + i),
+                                    backend=be)
 
         merge_w = lw if weighted_merge else (lw > 0).astype(xs.dtype)
 
@@ -379,6 +381,8 @@ class ChunkDistStats(NamedTuple):
     per_device_points: tuple  # rows folded by each device's shard
     per_device_chunks: tuple  # chunks consumed by each device's shard
     peak_pool_rows: int      # most pool rows alive on any ONE device
+    per_device_bytes: tuple  # chunk bytes fed to each device over the
+    #                          fit's data passes
 
 
 def merge_pool_distributed(pools, pool_ws, spec: ClusterSpec,
@@ -402,7 +406,10 @@ def merge_pool_distributed(pools, pool_ws, spec: ClusterSpec,
     whatever space the pools are in — the caller unscales) and the true
     Lloyd round count (``spec.merge.effective_stop.max_iters`` under the
     default ``tol=0`` policy; less when the psum'd convergence scalar
-    exits early)."""
+    exits early).  ``spec.merge.init`` and ``spec.merge.restarts`` are
+    ignored: the merge seeds by one greedy farthest-point pick.  The
+    jitted program is built once per mesh, merge spec and backend
+    (:func:`_merge_program`), so later fits compile nothing."""
     be = get_backend(backend if backend is not None
                      else spec.execution.backend)
     axis = spec.execution.mesh_axis
@@ -430,19 +437,28 @@ def merge_pool_distributed(pools, pool_ws, spec: ClusterSpec,
     sharding = jax.sharding.NamedSharding(mesh, P(axis))
     dc = jax.device_put(all_c, sharding)
     dw = jax.device_put(merge_w, sharding)
-    k, stop = spec.merge.k, spec.merge.effective_stop
+    merge = _merge_program(mesh, axis, spec.merge.k,
+                           spec.merge.effective_stop, be)
+    return merge(dc, dw, key)
+
+
+@functools.lru_cache(maxsize=32)
+def _merge_program(mesh: jax.sharding.Mesh, axis: str, k: int,
+                   stop: StopSpec, backend: LloydBackend):
+    """The jitted shard_map of :func:`_distributed_merge`, built once per
+    (mesh, axis, k, stop, backend) and reused across fits, so its compiled
+    program (one per pool shape) is found again instead of recompiled."""
     def merge_body(lc, lw, kk):
         with scope("merge"):
-            return _distributed_merge(lc, lw, k, stop, kk, axis, be)
+            return _distributed_merge(lc, lw, k, stop, kk, axis, backend)
 
-    body = compat.shard_map(
+    return jax.jit(compat.shard_map(
         merge_body,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P()),
         out_specs=(P(), P()),
         check_vma=False,
-    )
-    return jax.jit(body)(dc, dw, key)
+    ))
 
 
 def fit_chunked_dist(source, spec: ClusterSpec, mesh: jax.sharding.Mesh,
@@ -478,6 +494,14 @@ def fit_chunked_dist(source, spec: ClusterSpec, mesh: jax.sharding.Mesh,
     The merge key is the same ``key_global`` half :func:`fit_chunked`
     uses, so the distributed merge agrees with
     ``merge_pool_distributed`` on the same pools under the same key.
+
+    Compile contract: the per-chunk fold (``_fold_scaled_chunk``), each
+    reduce level (``reduce_pool``) and the distributed merge
+    (``_merge_program``) are compiled programs reused across fits, so with
+    ``merge_path="distributed"`` a fit compiles nothing once a fit of the
+    same shapes has run.  The chunk transfers are host spans ``feed``
+    (``prefetch_to_device``); the summary event reports the chunk bytes
+    fed to each device (``per_device_bytes``).
 
     Empty shards (fewer chunks than devices) are tolerated at runtime —
     they simply contribute nothing; ``plan()`` rejects the configurations
@@ -546,6 +570,7 @@ def fit_chunked_dist(source, spec: ClusterSpec, mesh: jax.sharding.Mesh,
     dropped = [None] * n_dev
     dev_points = [0] * n_dev
     dev_chunks = [0] * n_dev
+    dev_bytes = [0] * n_dev      # one pass's chunk bytes, per device
     max_chunk = 0
     dev_iters = [None] * n_dev   # per-device true Lloyd-iteration counts
     fold_budget = 0              # sum of max_iters budgets
@@ -585,6 +610,7 @@ def fit_chunked_dist(source, spec: ClusterSpec, mesh: jax.sharding.Mesh,
                 fold_budget += lv.effective_stop.max_iters * lv.n_sub
                 dev_points[i] += m
                 dev_chunks[i] += 1
+                dev_bytes[i] += int(chunk.nbytes)
                 max_chunk = max(max_chunk, m)
                 fold_rate.tick(m, device=i, chunk=j, rows=m)
         # each device's chunk folds add into its dev_iters entry
@@ -686,12 +712,16 @@ def fit_chunked_dist(source, spec: ClusterSpec, mesh: jax.sharding.Mesh,
                            passes=passes, n_devices=n_dev,
                            per_device_points=tuple(dev_points),
                            per_device_chunks=tuple(dev_chunks),
-                           peak_pool_rows=peak_pool)
+                           peak_pool_rows=peak_pool,
+                           # every data pass walks the same chunks
+                           per_device_bytes=tuple(b * passes
+                                                  for b in dev_bytes))
     if log is not NULL:
         wall = _now() - t_start   # the stages waited: "result ready"
         summary = stats._asdict()
         summary["per_device_points"] = list(stats.per_device_points)
         summary["per_device_chunks"] = list(stats.per_device_chunks)
+        summary["per_device_bytes"] = list(stats.per_device_bytes)
         log.event("fit_chunked_dist", k=spec.merge.k, levels=spec.n_levels,
                   backend=be.name, merge_path=spec.execution.merge_path,
                   wall_s=wall, points_per_sec=n_points / max(wall, 1e-9),

@@ -160,6 +160,7 @@ def merge_pool(pool: Array, pool_w: Array, merge: MergeSpec, key: Array, *,
                   restarts=merge.restarts)
 
 
+@functools.partial(jax.jit, static_argnames=("level", "backend"))
 def reduce_pool(pool: Array, pool_w: Array, level: LevelSpec, key: Array,
                 backend: BackendSpec = None) -> tuple[Array, Array, Array]:
     """One level of the hierarchical reduce tree: re-partition a weighted
@@ -177,21 +178,29 @@ def reduce_pool(pool: Array, pool_w: Array, level: LevelSpec, key: Array,
     points — the third return value is that dropped mass (0.0 for
     ``equal``), which :func:`fit_from_spec` folds into the result's
     ``n_dropped``.
+
+    Jitted with ``level`` and ``backend`` static: one compiled program per
+    (pool shape, level spec, backend), so the chunked executors' level
+    chains and early flushes compile nothing after their first fit, and a
+    caller that is itself traced inlines it.  The device scope
+    ``reduce_level`` names its operations in a trace (a caller's stage
+    timer stays outside).
     """
     be = get_backend(backend)
-    part = get_partitioner(level.scheme)(pool, level.n_sub,
-                                         level.capacity_factor)
-    parts, part_w = gather_partitions(pool, part, weights=pool_w)
-    w_dropped = jnp.sum(pool_w).astype(jnp.float32) - \
-        jnp.sum(part_w).astype(jnp.float32)
-    k_local = max(1, parts.shape[1] // level.compression)
-    local = local_stage(parts, part_w, k_local, key=key,
-                        init=level.init, backend=be,
-                        stop=level.effective_stop)
-    d = pool.shape[-1]
-    return (local.centers.reshape(level.n_sub * k_local, d),
-            local.counts.reshape(level.n_sub * k_local),
-            jnp.maximum(w_dropped, 0.0))
+    with scope("reduce_level"):
+        part = get_partitioner(level.scheme)(pool, level.n_sub,
+                                             level.capacity_factor)
+        parts, part_w = gather_partitions(pool, part, weights=pool_w)
+        w_dropped = jnp.sum(pool_w).astype(jnp.float32) - \
+            jnp.sum(part_w).astype(jnp.float32)
+        k_local = max(1, parts.shape[1] // level.compression)
+        local = local_stage(parts, part_w, k_local, key=key,
+                            init=level.init, backend=be,
+                            stop=level.effective_stop)
+        d = pool.shape[-1]
+        return (local.centers.reshape(level.n_sub * k_local, d),
+                local.counts.reshape(level.n_sub * k_local),
+                jnp.maximum(w_dropped, 0.0))
 
 
 def _log_stage_iters(log, stage: str, iters_run: int,

@@ -51,6 +51,8 @@ from typing import Callable, Iterable, Iterator, Optional
 import jax
 import numpy as np
 
+from repro.telemetry import scope
+
 Array = jax.Array
 
 
@@ -361,7 +363,8 @@ def prefetch_to_device(chunks: Iterable, depth: int = 2, *,
     executor gives each shard its own device this way).  Chunks that are
     already single-device jax arrays in the right place are yielded as-is
     instead of paying a redundant copy — the ``ArraySource``-over-jax-array
-    case.
+    case.  Each transfer's dispatch is the host span ``feed`` on the
+    profiler's clock (``device_put`` may return before the copy ends).
     """
     if depth < 1:
         raise ValueError(f"prefetch_to_device: depth must be >= 1, "
@@ -370,7 +373,8 @@ def prefetch_to_device(chunks: Iterable, depth: int = 2, *,
     def _put(x):
         if _device_resident(x, device):
             return x
-        return jax.device_put(x, device)
+        with scope("feed"):
+            return jax.device_put(x, device)
 
     it = iter(chunks)
     buf: collections.deque = collections.deque()
